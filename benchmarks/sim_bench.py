@@ -58,6 +58,7 @@ from repro.core import admission, fleet as F
 from repro.core import risk, solver, spatial, stats, vcc
 from repro.core import stages as stages_mod
 from repro.core.stages import hour_sum
+from repro.launch.cache import enable_compile_cache
 from repro.sim import (SimConfig, Scenario, build_batch, build_params,
                        default_library, forecast_bust_library,
                        make_day_step, make_init, make_rollout,
@@ -732,6 +733,7 @@ def main():
                     help="output json path (default: repo-root "
                          "BENCH_sim.json)")
     args = ap.parse_args()
+    enable_compile_cache()
     rows = run(quick=args.quick, out_path=args.out)
     by_name = {name: val for name, val, _ in rows}
     for name, val, derived in rows:

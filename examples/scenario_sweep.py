@@ -10,9 +10,10 @@ flexible-work completion within 24h.
                                                      [--sharded]
 
 ``--sharded`` runs the same batch through `rollout_batch_sharded`: the
-(scenario x seed) axis is shard_map'd over every local device (bitwise
-identical results — the engine's parity contract — so the table does not
-change, only the wall clock on multi-device hosts).
+(scenario x seed) axis is shard_map'd over every local device. On one
+device the table is bitwise the same; across devices each device's
+program may round differently, which moves single clusters but keeps
+fleet totals within 1% (the engine's parity contract, see README).
 
 Reading the table: carbon-priced scenarios trade peak power for carbon
 (negative peakRed% — the 'War of the Efficiencies'); `peak_shaver` flips
@@ -49,6 +50,7 @@ import time
 
 import jax
 
+from repro.launch.cache import enable_compile_cache
 from repro.sim import (MOBILITY_COLUMNS, RISK_COLUMNS, RISK_MEMBERS,
                        SimConfig, TELEMETRY_COLUMNS, build_batch,
                        default_library, format_table,
@@ -119,7 +121,7 @@ def main():
     ap.add_argument("--hist", type=int, default=28)
     ap.add_argument("--sharded", action="store_true",
                     help="shard the (scenario x seed) batch over all "
-                         "local devices (bitwise-identical results)")
+                         "local devices")
     ap.add_argument("--risk", action="store_true",
                     help="run the CVaR risk-sweep family (beta x K) "
                          "instead of the default library")
@@ -135,6 +137,7 @@ def main():
                     help="with --telemetry: also write the per scenario x "
                          "seed x day trace records to PATH as JSONL")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.days < 1 or args.seeds < 1:
         ap.error("--days and --seeds must be >= 1")
     if args.risk and args.spatial:

@@ -27,29 +27,6 @@ import jax.numpy as jnp
 f32 = jnp.float32
 
 
-def register_barrier_batching():
-    """jax<=0.4 ships no vmap rule for optimization_barrier (newer jax
-    does). The rule is the identity on batch dims: barrier each operand,
-    keep its batch axis. Lives here (the lowest module that emits
-    barriers — ``ewma_update`` pins its products); ``stages`` re-uses it."""
-    try:
-        from jax._src.interpreters import batching
-        from jax._src.lax import lax as _lax
-        prim = _lax.optimization_barrier_p
-    except (ImportError, AttributeError):    # pragma: no cover
-        return
-    if prim in batching.primitive_batchers:
-        return
-
-    def rule(args, dims):
-        return prim.bind(*args), dims
-
-    batching.primitive_batchers[prim] = rule
-
-
-register_barrier_batching()
-
-
 def ewma_alpha(half_life) -> jnp.ndarray:
     """One-step EWMA weight for a given half-life, measured in UPDATE
     STEPS (weeks for the weekly rescan, days for the streaming carry).

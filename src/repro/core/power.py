@@ -83,17 +83,26 @@ def fit_pd_model(cpu: jnp.ndarray, power: jnp.ndarray
     cpu, power: (t,). Returns (coef (K+2,), breaks (K,))."""
     qs = jnp.linspace(0.0, 1.0, N_BREAKS + 2)[1:-1]
     breaks = jnp.quantile(cpu, qs)
-    X = _basis(cpu, breaks)
-    # ridge-regularized normal equations (stable under short windows),
-    # assembled as reduce-of-multiply rather than dots: XLA dots pick
-    # batch-extent-dependent accumulation orders, plain reduces have held
-    # batch-invariant here (and fleet.power_model_from_history pins the
-    # result behind an optimization_barrier; the sim parity tests would
+    # Fit in window coordinates x = (u - lo) / span in [0, 1]. In raw
+    # usage a PD that idles in a narrow band makes the constant and
+    # linear columns nearly collinear, and float32 normal equations then
+    # return coefficients of 1e10 whose predictions are off by orders of
+    # magnitude, and differently on every backend. The ridge is relative
+    # to the window length, which bounds cond(XtX) near 1e4 when
+    # breakpoints coincide.
+    lo = jnp.min(cpu)
+    span = jnp.clip(jnp.max(cpu) - lo, 1e-6, None)
+    X = _basis((cpu - lo) / span, (breaks - lo) / span)
+    # normal equations assembled as reduce-of-multiply rather than dots:
+    # XLA dots pick batch-extent-dependent accumulation orders, plain
+    # reduces have held batch-invariant here (the sim parity tests would
     # catch a backend that reassociates these)
     XtX = jnp.sum(X[..., :, None] * X[..., None, :], axis=-3) \
-        + 1e-4 * jnp.eye(X.shape[-1])
+        + 1e-4 * cpu.shape[-1] * jnp.eye(X.shape[-1])
     Xty = jnp.sum(X * power[..., None], axis=-2)
-    coef = _solve_spd(XtX, Xty)
+    cx = _solve_spd(XtX, Xty)
+    # back to usage coordinates: relu(x - q_k) = relu(u - b_k) / span
+    coef = jnp.concatenate([cx[:1] - cx[1:2] * lo / span, cx[1:] / span])
     return coef, breaks
 
 

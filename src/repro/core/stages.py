@@ -49,11 +49,6 @@ f32 = jnp.float32
 hour_sum = admission.hour_sum
 
 
-# jax<=0.4 vmap-rule shim for optimization_barrier — registered by the
-# lowest barrier-emitting module (forecast.ewma_update pins its products)
-forecast.register_barrier_batching()
-
-
 # ------------------------------------------------------------- fleet synth
 
 def cluster_truth(key, n: int):

@@ -53,6 +53,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import forecast
+from repro.core.admission import hour_sum
 
 f32 = jnp.float32
 
@@ -434,26 +435,25 @@ class HourAccum(NamedTuple):
     pushes one observed hour at a time; ``hour_finalize`` absorbs the
     completed day into the ``PredictorState`` carry.
 
-    Columns are scattered in hour order and the daily totals accumulate
-    by the SAME ordered adds as ``admission.hour_sum``, so chaining 24
-    ``hour_update`` calls and finalizing is BITWISE identical to the
-    daily batch ``predictor_update`` on the assembled arrays
-    (property-tested in tests/test_mpc_properties.py)."""
+    Columns are scattered in hour order and ``hour_finalize`` takes the
+    daily totals of the assembled columns by ``admission.hour_sum``, so
+    chaining 24 ``hour_update`` calls and finalizing is BITWISE identical
+    to the daily batch ``predictor_update`` on the same arrays
+    (property-tested in tests/test_mpc_properties.py). A running total
+    per hour would not be: the compiler may fuse ``+ usage * ratio``
+    into one fused multiply-add, which rounds once where the batch path
+    rounds twice."""
     hour: jnp.ndarray            # () int32 hours absorbed so far
     u_if: jnp.ndarray            # (n, 24) realized inflexible columns
     use_flex: jnp.ndarray        # (n, 24) realized flexible columns
     usage: jnp.ndarray           # (n, 24) u_if + use_flex
     res: jnp.ndarray             # (n, 24) reservations = usage * ratio
-    flex_daily: jnp.ndarray      # (n,) ordered running sum of use_flex
-    res_daily: jnp.ndarray       # (n,) ordered running sum of res
 
 
 def hour_accum_init(n: int) -> HourAccum:
     z24 = jnp.zeros((n, 24), f32)
     return HourAccum(hour=jnp.zeros((), jnp.int32), u_if=z24,
-                     use_flex=z24, usage=z24, res=z24,
-                     flex_daily=jnp.zeros((n,), f32),
-                     res_daily=jnp.zeros((n,), f32))
+                     use_flex=z24, usage=z24, res=z24)
 
 
 def hour_update(acc: HourAccum, hour, u_if_h, use_flex_h, ratio_h
@@ -468,10 +468,7 @@ def hour_update(acc: HourAccum, hour, u_if_h, use_flex_h, ratio_h
         u_if=acc.u_if.at[:, hour].set(u_if_h),
         use_flex=acc.use_flex.at[:, hour].set(use_flex_h),
         usage=acc.usage.at[:, hour].set(usage_h),
-        res=acc.res.at[:, hour].set(res_h),
-        # ordered adds in ascending-hour order == admission.hour_sum
-        flex_daily=acc.flex_daily + use_flex_h,
-        res_daily=acc.res_daily + res_h)
+        res=acc.res.at[:, hour].set(res_h))
 
 
 def hour_finalize(pred: PredictorState, acc: HourAccum,
@@ -480,5 +477,5 @@ def hour_finalize(pred: PredictorState, acc: HourAccum,
     streaming carry. Equals the daily batch ``predictor_update`` on the
     same realized arrays (the accumulator reconstructs them exactly)."""
     return predictor_update(pred, fc, day, gamma, acc.u_if,
-                            acc.flex_daily, acc.res_daily, acc.usage,
-                            acc.res)
+                            hour_sum(acc.use_flex), hour_sum(acc.res),
+                            acc.usage, acc.res)

@@ -9,3 +9,10 @@ Each kernel package ships ``kernel.py`` (pl.pallas_call + explicit BlockSpec
 VMEM tiling), ``ops.py`` (jit'd dispatching wrapper) and ``ref.py`` (pure-jnp
 oracle). Kernels are validated on CPU via ``interpret=True``.
 """
+import jax
+
+
+def tpu_available() -> bool:
+    """The kernels' auto-dispatch (``use_pallas=None``): the Pallas kernel
+    where JAX's default backend is a TPU, the jnp oracle elsewhere."""
+    return jax.default_backend() == "tpu"

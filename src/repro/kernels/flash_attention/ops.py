@@ -5,16 +5,9 @@ from __future__ import annotations
 
 from typing import Optional
 
-import jax
 
+from repro.kernels import tpu_available
 from repro.kernels.flash_attention import ref as _ref
-
-
-def _tpu_available() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
 
 
 def attention(q, k, v, *, causal: bool = True, window: Optional[int] = None,
@@ -29,7 +22,7 @@ def attention(q, k, v, *, causal: bool = True, window: Optional[int] = None,
     mode (CPU validation).
     """
     if use_pallas is None:
-        use_pallas = _tpu_available()
+        use_pallas = tpu_available()
     if use_pallas or interpret:
         from repro.kernels.flash_attention import kernel as _kernel
         return _kernel.flash_attention(

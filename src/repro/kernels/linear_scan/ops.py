@@ -3,18 +3,11 @@ from __future__ import annotations
 
 from typing import Optional
 
-import jax
 
+from repro.kernels import tpu_available
 from repro.kernels.linear_scan import ref as _ref
 
 gla_step = _ref.gla_step  # decode step is O(1); no kernel needed
-
-
-def _tpu_available() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
 
 
 def gla(q, k, v, log_decay, *, bonus=None, strict: bool = False,
@@ -22,7 +15,7 @@ def gla(q, k, v, log_decay, *, bonus=None, strict: bool = False,
         use_pallas: Optional[bool] = None, interpret: bool = False):
     """Chunked gated linear attention. See ``ref.gla_chunked`` for shapes."""
     if use_pallas is None:
-        use_pallas = _tpu_available()
+        use_pallas = tpu_available()
     if use_pallas or interpret:
         from repro.kernels.linear_scan import kernel as _kernel
         return _kernel.gla_pallas(

@@ -81,7 +81,9 @@ def _pgd_ens_kernel(delta_ref, eta_ref, pi_ref, pow_ref, tau_ref, price_ref,
     """CVaR ensemble epoch: blocks carry a (K, TC, H) member tile of
     eta/pow_nom; the member axis is reduced IN-KERNEL (per-cluster
     soft-CVaR tilt, anchored on member 0 — mirrors ref.pgd_step_ens_arrays
-    op for op, so identical members collapse bitwise)."""
+    op for op, so identical members collapse bitwise). Every member-axis
+    value keeps its trailing unit hour axis, (K, TC, 1): Mosaic lowers no
+    (TC, 1) -> (TC,) relayout of a kernel value."""
     delta = delta_ref[...].astype(jnp.float32)          # (TC, H)
     eta_e = eta_ref[...].astype(jnp.float32)            # (K, TC, H)
     pi = pi_ref[...].astype(jnp.float32)
@@ -101,15 +103,16 @@ def _pgd_ens_kernel(delta_ref, eta_ref, pi_ref, pow_ref, tau_ref, price_ref,
         s = s - jnp.max(s, axis=-1, keepdims=True)
         e = jnp.exp(s)
         w_peak = e / jnp.sum(e, axis=-1, keepdims=True)
-        cost = lambda_e[..., 0][None] * jnp.sum(eta_e * ph, axis=-1) \
-            + price[..., 0][None] * jnp.sum(w_peak * ph, axis=-1)  # (K, TC)
+        cost = lambda_e[None] * jnp.sum(eta_e * ph, axis=-1, keepdims=True) \
+            + price[None] * jnp.sum(w_peak * ph, axis=-1,
+                                    keepdims=True)      # (K, TC, 1)
         z = cost - cost[:1]
         dev = cost - jnp.mean(cost, axis=0, keepdims=True)
         scale = jnp.mean(jnp.abs(dev), axis=0, keepdims=True) + 1e-9
-        t = risk_s[..., 0][None] * z / scale
+        t = risk_s[None] * z / scale
         t = t - jnp.max(t, axis=0, keepdims=True)
         et = jnp.exp(t)
-        wm = (et / jnp.sum(et, axis=0, keepdims=True))[..., None]
+        wm = et / jnp.sum(et, axis=0, keepdims=True)     # (K, TC, 1)
         eta_w = eta_e[0] + jnp.sum(wm * (eta_e - eta_e[:1]), axis=0)
         w_w = w_peak[0] + jnp.sum(wm * (w_peak - w_peak[:1]), axis=0)
         grad = (lambda_e * eta_w + price * w_w) * pi * tau24

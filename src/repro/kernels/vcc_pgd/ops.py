@@ -10,17 +10,10 @@ from __future__ import annotations
 
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels import tpu_available
 from repro.kernels.vcc_pgd import ref as _ref
-
-
-def _tpu_available() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
 
 
 def pgd_epoch(prob, delta, mu, lo, ub, lr_eff, temp, iters,
@@ -40,7 +33,7 @@ def pgd_epoch(prob, delta, mu, lo, ub, lr_eff, temp, iters,
         if jnp.ndim(lr_eff) < 2 else lr_eff.astype(jnp.float32)
     kw = dict(temp=temp, lambda_e=prob.lambda_e, iters=int(iters))
     if use_pallas is None:
-        use_pallas = _tpu_available()
+        use_pallas = tpu_available()
     if getattr(prob, "eta_ens", None) is not None:
         kw["risk_s"] = _ref.cvar_sharpness(prob.risk_beta)
         if use_pallas or interpret:
@@ -80,7 +73,7 @@ def joint_step(prob, delta, s, mu, lr_d, temp,
     kw = dict(temp=temp, lambda_e=prob.lambda_e,
               drop_limit=float(prob.drop_limit))
     if use_pallas is None:
-        use_pallas = _tpu_available()
+        use_pallas = tpu_available()
     if use_pallas or interpret:
         from repro.kernels.vcc_pgd import kernel as _kernel
         return _kernel.joint_step_pallas(

@@ -25,7 +25,7 @@ import jax
 from repro.configs import ARCHS, SHAPES, get_arch
 from repro.launch import roofline as rl
 from repro.launch.hlo_analysis import analyze_hlo
-from repro.launch.mesh import make_production_mesh, use_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.models import (batch_specs, build_model, cache_specs, decode_specs,
                           param_specs)
 from repro.optim import AdamWConfig, init_opt_state
@@ -64,7 +64,7 @@ def lower_cell(arch_name: str, shape_name: str, multi_pod: bool,
                              devices=jax.devices()[:256])
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)
-    with use_mesh(mesh), activation_sharding(mesh):
+    with jax.set_mesh(mesh), activation_sharding(mesh):
         return _lower_cell_inner(cfg, shape, mesh, multi_pod)
 
 

@@ -7,20 +7,6 @@ import numpy as np
 import jax
 
 
-def use_mesh(mesh):
-    """Ambient-mesh context manager across JAX versions.
-
-    ``jax.set_mesh`` landed well after 0.4.x; on older releases the Mesh
-    object itself is the context manager that installs the ambient mesh
-    (needed for bare-PartitionSpec sharding constraints in act.py).
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return mesh
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """TPU v5e production mesh: 16x16 (256 chips) per pod; 2 pods = 512.
 
@@ -54,17 +40,6 @@ def make_batch_mesh(n_devices=None):
     if n < 1 or n > len(devices):
         raise ValueError(f"need 1..{len(devices)} devices, asked for {n}")
     return jax.make_mesh((n,), ("batch",), devices=devices[:n])
-
-
-def shard_map_compat(fn, mesh, in_specs, out_specs):
-    """shard_map across JAX versions (`jax.shard_map` landed after 0.4.x;
-    older releases ship it under jax.experimental)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs)
 
 
 def make_local_mesh(model_parallel: int = 1):

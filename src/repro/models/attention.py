@@ -230,11 +230,6 @@ def _mla_flash_decode(mesh, q_eff, q_rope, ckv_new, krope_new, ckv_c,
                 P(ba, "model", None), P(ba, "model", None), P())
     out_specs = (P(ba, None, None, None), P(ba, "model", None),
                  P(ba, "model", None))
-    if hasattr(jax, "shard_map"):
-        mapped = jax.shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_vma=False)
-    else:  # jax<=0.4: experimental API, replication check flag spelled
-        from jax.experimental.shard_map import shard_map
-        mapped = shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_rep=False)
+    mapped = jax.shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
     return mapped(q_eff, q_rope, ckv_new, krope_new, ckv_c, krope_c, pos)

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import layers as L
-from repro.sharding.act import constrain
+from repro.sharding.act import constrain, out_sharding
 
 
 def init_moe(key, cfg: ModelConfig, dtype):
@@ -97,7 +97,11 @@ def _dispatch_einsum(p, m, xg, topv, topi, no_drop=False):
         oh = oh * keep[..., j, None, None].astype(xg.dtype)
         dispatch = dispatch + oh
         combine = combine + oh.astype(jnp.float32) * topv[..., j, None, None]
-    xe = jnp.einsum("gsec,gsd->gecd", dispatch, xg)
+    # s is contracted and may be sharded on both sides: name the output's
+    # sharding, which jax otherwise refuses to guess
+    xe = jnp.einsum("gsec,gsd->gecd", dispatch, xg,
+                    out_sharding=out_sharding((G, E, C, D), "batch",
+                                              "model", None, None))
     xe = constrain(xe, "batch", "model", None, None)
     ye = _experts(p, xe)
     ye = constrain(ye, "batch", "model", None, None)

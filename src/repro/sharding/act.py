@@ -14,7 +14,7 @@ import contextlib
 import contextvars
 
 import jax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 _CTX: contextvars.ContextVar = contextvars.ContextVar("act_sharding",
                                                       default=None)
@@ -56,6 +56,18 @@ def batch_shards() -> int:
     return c["batch_size"] if c else 1
 
 
+def _spec(c, shape, dims) -> P:
+    spec = []
+    for i, d in enumerate(dims):
+        if d == "batch" and shape[i] % c["batch_size"] == 0:
+            spec.append(c["batch"])
+        elif d == "model" and shape[i] % c["model_size"] == 0:
+            spec.append(c["model"])
+        else:
+            spec.append(None)
+    return P(*spec)
+
+
 def constrain(x, *dims):
     """dims entries: 'batch' | 'model' | None, one per array dim.
     Dims whose size does not divide the named axis are left unconstrained."""
@@ -64,18 +76,20 @@ def constrain(x, *dims):
         return x
     if x.ndim != len(dims):
         return x
-    spec = []
-    for i, d in enumerate(dims):
-        if d == "batch" and x.shape[i] % c["batch_size"] == 0:
-            spec.append(c["batch"])
-        elif d == "model" and x.shape[i] % c["model_size"] == 0:
-            spec.append(c["model"])
-        else:
-            spec.append(None)
     try:
-        return jax.lax.with_sharding_constraint(x, P(*spec))
+        return jax.lax.with_sharding_constraint(x, _spec(c, x.shape, dims))
     except Exception:       # no ambient mesh (e.g. eager test) -> no-op
         return x
+
+
+def out_sharding(shape, *dims):
+    """The sharding ``constrain(x, *dims)`` would give an array of
+    ``shape``, as an ``out_sharding`` for ops whose output sharding is
+    ambiguous (a contraction over a sharded dim); None when inactive."""
+    c = _CTX.get()
+    if c is None:
+        return None
+    return NamedSharding(c["mesh"], _spec(c, shape, dims))
 
 
 def constrain_tree(tree, *dims):

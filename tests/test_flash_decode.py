@@ -14,7 +14,6 @@ import jax, jax.numpy as jnp, numpy as np, dataclasses
 from repro.configs import get_arch
 from repro.models import build_model
 from repro.sharding.act import activation_sharding
-from repro.launch.mesh import use_mesh
 
 cfg = get_arch('deepseek-v2-236b').smoke.replace(dtype='float32',
                                                  remat='none')
@@ -30,7 +29,7 @@ lg_base, _ = model.decode_step(params, cache, toks[:, T-1],
                                jnp.asarray(T-1, jnp.int32))
 mesh = jax.make_mesh((2, 4), ("data", "model"), devices=jax.devices()[:8])
 model2 = build_model(cfg.replace(flash_decode=True))
-with use_mesh(mesh), activation_sharding(mesh):
+with jax.set_mesh(mesh), activation_sharding(mesh):
     _, cache2 = model2.prefill(params, {'tokens': toks[:, :T-1]}, maxs)
     lg_flash, _ = jax.jit(model2.decode_step)(params, cache2, toks[:, T-1],
                                               jnp.asarray(T-1, jnp.int32))

@@ -32,7 +32,7 @@ SET = dict(max_examples=25, deadline=None,
     pred=hnp.arrays(np.float32, (30,),
                     elements=st.floats(0.5, 10.0, width=32)),
     act=hnp.arrays(np.float32, (30,),
-                   elements=st.floats(0.1, 20.0, width=32)),
+                   elements=st.floats(0.125, 20.0, width=32)),
     q1=st.floats(0.05, 0.95),
     dq=st.floats(0.0, 0.049),
 )
@@ -50,7 +50,7 @@ def test_relative_error_quantile_monotone_in_q(pred, act, q1, dq):
 @given(
     x=hnp.arrays(np.float32, (21,),
                  elements=st.floats(0.0, 100.0, width=32)),
-    hl=st.floats(0.1, 16.0),
+    hl=st.floats(0.125, 16.0),
 )
 @settings(**SET)
 def test_ewma_bounded_by_input_range(x, hl):
@@ -62,8 +62,8 @@ def test_ewma_bounded_by_input_range(x, hl):
 
 @given(
     daily=hnp.arrays(np.float32, (28,),
-                     elements=st.floats(0.1, 50.0, width=32)),
-    hl=st.floats(0.1, 8.0),
+                     elements=st.floats(0.125, 50.0, width=32)),
+    hl=st.floats(0.125, 8.0),
 )
 @settings(**SET)
 def test_weekly_mean_forecast_bounded_by_input_range(daily, hl):
@@ -75,7 +75,7 @@ def test_weekly_mean_forecast_bounded_by_input_range(daily, hl):
 
 @given(
     uif=hnp.arrays(np.float32, (24,),
-                   elements=st.floats(0.1, 5.0, width=32)),
+                   elements=st.floats(0.125, 5.0, width=32)),
     tuf=st.floats(0.5, 20.0),
     ratio_a=st.floats(1.05, 2.0),
     eps=st.floats(0.0, 2.0),
@@ -111,7 +111,7 @@ def test_alpha_inflation_geq_one_and_monotone_in_error(uif, tuf, ratio_a,
 
 
 @given(
-    tr=st.floats(0.1, 100.0),
+    tr=st.floats(0.125, 100.0),
     eps=st.floats(-1.0, 3.0),
 )
 @settings(**SET)

@@ -182,8 +182,14 @@ def test_solve_joint_jit_and_vmap():
 
 def test_joint_rollout_through_engine():
     """SimConfig(joint_spatial=True) runs the mobility sweep end to end:
-    finite ledgers, and the mobility=0 row matches the sequential-path
-    rollout of the same scenario (both graphs pin the shift to zero)."""
+    finite ledgers, and the mobility=0 row stays close to the
+    sequential-path rollout of the same scenario. Both graphs pin the
+    shift to zero, but the joint graph still runs its delta refinement
+    from the sequential warm start and keeps it wherever it weakly
+    improves the plan (``spatial.solve_joint`` step 4). On jax 0.9 CPU
+    it keeps it on both days (telemetry ``joint_winner`` = 1), which
+    moves realized carbon by 5.1e-4 relative: a different plan, not
+    rounding, hence rtol 1e-3."""
     from repro.sim import (SimConfig, build_batch, mobility_sweep_library,
                            rollout_batch)
     days, seeds = 2, [0]
@@ -197,11 +203,10 @@ def test_joint_rollout_through_engine():
         _, led[joint], _ = rollout_batch(cfg, days)(batch)
     for b in (True, False):
         assert np.isfinite(np.asarray(led[b].carbon_kg)).all()
-    # mobility=0 (batch row 0): joint graph == sequential graph to float
-    # tolerance (different XLA programs, same math — s pinned to 0)
+    # mobility=0 (batch row 0): s pinned to 0, delta refined (docstring)
     np.testing.assert_allclose(np.asarray(led[True].carbon_kg[0]),
                                np.asarray(led[False].carbon_kg[0]),
-                               rtol=1e-4)
+                               rtol=1e-3)
 
 
 def test_joint_with_ensemble_stage():

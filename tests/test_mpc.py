@@ -120,8 +120,9 @@ def test_mpc_day_gate_closed_is_open_loop_bitwise():
     # no recourse accepted, accumulator saw all 24 hours
     assert float(diag.recourse_frac.max()) == 0.0
     assert int(acc.hour) == 24
-    np.testing.assert_array_equal(np.asarray(acc.flex_daily),
-                                  np.asarray(res.served))
+    np.testing.assert_array_equal(
+        np.asarray(admission.hour_sum(acc.use_flex)),
+        np.asarray(res.served))
 
 
 def test_mpc_day_triggers_on_intensity_divergence():

@@ -62,7 +62,7 @@ def _predictor(seed=0):
 
 @given(
     u_if=hnp.arrays(np.float32, (N, 24),
-                    elements=st.floats(0.01, 2.0, width=32)),
+                    elements=st.floats(0.0078125, 2.0, width=32)),
     use_flex=hnp.arrays(np.float32, (N, 24),
                         elements=st.floats(0.0, 1.0, width=32)),
     ratio=hnp.arrays(np.float32, (N, 24),
@@ -95,7 +95,7 @@ def test_hourly_chain_equals_daily_batch_update_bitwise(u_if, use_flex,
 @given(
     hour=st.integers(1, 23),
     jitter=hnp.arrays(np.float32, (6, 24),
-                      elements=st.floats(-0.3, 0.3, width=32)),
+                      elements=st.floats(-0.3125, 0.3125, width=32)),
     seed=st.integers(0, 3),
 )
 @settings(**SET)

@@ -85,7 +85,7 @@ def test_carbon_intensity_positive_bounded(seed):
 
 @given(
     seed=st.integers(0, 2**16),
-    scale=st.floats(0.1, 10.0),
+    scale=st.floats(0.125, 10.0),
 )
 @settings(**SET)
 def test_compression_error_feedback_unbiased(seed, scale):

@@ -36,7 +36,7 @@ SET = dict(max_examples=25, deadline=None,
 @given(
     x=hnp.arrays(np.float32, (20,),
                  elements=st.floats(0.0, 100.0, width=32)),
-    hl=st.floats(0.1, 16.0),
+    hl=st.floats(0.125, 16.0),
 )
 @settings(**SET)
 def test_ewma_incremental_matches_batch_scan_bitwise(x, hl):

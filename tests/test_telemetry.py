@@ -124,7 +124,13 @@ def test_telemetry_off_traj_keys_unchanged():
 def test_batched_telemetry_matches_sequential_bitwise():
     """A vmap'd batch's DayTelemetry must reproduce each scenario's
     non-batched sequential rollout telemetry BITWISE — same contract,
-    same idiom as tests/test_stages_parity.py for the ledger."""
+    same idiom as tests/test_stages_parity.py for the ledger.
+
+    One channel is held to one float32 ulp instead: ``obj_cluster_traj``
+    (``vcc.cluster_objective`` per outer round) is a sum of products,
+    which XLA's CPU backend fuses into multiply-adds in one program and
+    not in the other; an ``optimization_barrier`` does not stop that
+    fusion. Measured on jax 0.9 CPU: 1 ulp (6.5e-8 relative)."""
     cfg = SimConfig(**CFG_KW, telemetry=True)
     scens = default_library(DAYS)[:3]
     batch = build_batch(cfg, scens, [0], DAYS)
@@ -134,10 +140,15 @@ def test_batched_telemetry_matches_sequential_bitwise():
     for i, sc in enumerate(scens):
         p = build_params(cfg, sc, 0, DAYS)
         _, _, traj = roll(p, init(p))
-        for a, b in zip(jax.tree.leaves(trajB["telemetry"]),
-                        jax.tree.leaves(traj["telemetry"])):
-            np.testing.assert_array_equal(np.asarray(a[i]), np.asarray(b),
-                                          err_msg=sc.name)
+        for name in DayTelemetry._fields:
+            a = np.asarray(getattr(trajB["telemetry"], name)[i])
+            b = np.asarray(getattr(traj["telemetry"], name))
+            if name == "obj_cluster_traj":
+                np.testing.assert_allclose(a, b, rtol=2.0 ** -23, atol=0,
+                                           err_msg=f"{sc.name} {name}")
+            else:
+                np.testing.assert_array_equal(a, b,
+                                              err_msg=f"{sc.name} {name}")
 
 
 # ----------------------------------------------------- solver-channel sanity
