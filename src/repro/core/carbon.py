@@ -71,7 +71,7 @@ def simulate_zone_from(key, zp: dict, days: int) -> jnp.ndarray:
         def step(x, e):
             x = rho * x + jnp.sqrt(1 - rho ** 2) * e
             return x, x
-        _, xs = jax.lax.scan(step, jnp.zeros(()), eps)
+        _, xs = jax.lax.scan(step, jnp.zeros_like(eps[0]), eps)
         return xs
     clear = jax.nn.sigmoid(1.0 + ar1(k1, days, vol=zp["weather_vol"] * 5))
     windy = jax.nn.sigmoid(0.5 + ar1(k2, days, vol=zp["weather_vol"] * 6))

@@ -24,6 +24,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.admission import hour_sum
+
 f32 = jnp.float32
 
 
@@ -185,11 +187,13 @@ def theta_requirement(tr_pred_next: jnp.ndarray, eps_q97: jnp.ndarray
 def alpha_inflation(theta: jnp.ndarray, uif_pred: jnp.ndarray,
                     tuf_pred: jnp.ndarray, ratio_a, ratio_b) -> jnp.ndarray:
     """Solve eq. (3) for alpha: sum_h (U_IF(h) + a*T_UF/24) * R(h) = Theta,
-    with R evaluated at the nominal usage."""
+    with R evaluated at the nominal usage. The hour sums are ordered
+    (``admission.hour_sum``), like the day's other totals: a plain sum
+    rounded differently in a batch of one on XLA's CPU backend."""
     u_nom = uif_pred + tuf_pred / 24.0
     r = ratio_at(ratio_a, ratio_b, u_nom)
-    denom = jnp.clip(jnp.sum(tuf_pred / 24.0 * r), 1e-9, None)
-    alpha = (theta - jnp.sum(uif_pred * r)) / denom
+    denom = jnp.clip(hour_sum(tuf_pred / 24.0 * r), 1e-9, None)
+    alpha = (theta - hour_sum(uif_pred * r)) / denom
     return jnp.clip(alpha, 0.5, 4.0)
 
 

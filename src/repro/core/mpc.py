@@ -102,12 +102,12 @@ def mpc_day(prob: vcc.VCCProblem, sol: vcc.VCCSolution, tuf_fc, gate,
         delta=sol.delta,
         tau=tau0,
         mu=sol.mu,
-        acc=stats.hour_accum_init(n),
-        vcc_real=jnp.zeros((n, 24), f32),
-        arr_sofar=jnp.zeros((n,), f32),
-        mape_sum=jnp.zeros((n,), f32),
-        trig_hours=jnp.zeros((n,), f32),
-        depth_sum=jnp.zeros((n,), f32),
+        acc=stats.hour_accum_init(u_if),
+        vcc_real=jnp.zeros_like(u_if),
+        arr_sofar=jnp.zeros_like(tau0),
+        mape_sum=jnp.zeros_like(tau0),
+        trig_hours=jnp.zeros_like(tau0),
+        depth_sum=jnp.zeros_like(tau0),
     )
     xs = (jnp.arange(24), u_if.T, arrivals.T, ratio_true.T, intensity.T)
 

@@ -77,6 +77,19 @@ def _solve_spd(A: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     return jnp.stack(x, axis=-1)
 
 
+def _pairwise_sum(x: jnp.ndarray, axis: int) -> jnp.ndarray:
+    """Sum over ``axis`` by a fixed tree of elementwise adds (halves,
+    zero-padded when odd). Each add is elementwise, so the result is the
+    same bits at any batch extent, layout or device count."""
+    x = jnp.moveaxis(x, axis, 0)
+    while x.shape[0] > 1:
+        if x.shape[0] % 2:
+            x = jnp.concatenate([x, jnp.zeros_like(x[:1])])
+        half = x.shape[0] // 2
+        x = x[:half] + x[half:]
+    return x[0]
+
+
 def fit_pd_model(cpu: jnp.ndarray, power: jnp.ndarray
                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Least-squares piecewise-linear fit for ONE pd.
@@ -93,13 +106,13 @@ def fit_pd_model(cpu: jnp.ndarray, power: jnp.ndarray
     lo = jnp.min(cpu)
     span = jnp.clip(jnp.max(cpu) - lo, 1e-6, None)
     X = _basis((cpu - lo) / span, (breaks - lo) / span)
-    # normal equations assembled as reduce-of-multiply rather than dots:
-    # XLA dots pick batch-extent-dependent accumulation orders, plain
-    # reduces have held batch-invariant here (the sim parity tests would
-    # catch a backend that reassociates these)
-    XtX = jnp.sum(X[..., :, None] * X[..., None, :], axis=-3) \
+    # normal equations summed by _pairwise_sum, not dots or reduces: XLA
+    # picks their accumulation order from the layout of the batch around
+    # them (on a TPU v5e a batch of 11 rollouts rounded differently from
+    # the same rollouts in a batch of 44)
+    XtX = _pairwise_sum(X[..., :, None] * X[..., None, :], axis=-3) \
         + 1e-4 * cpu.shape[-1] * jnp.eye(X.shape[-1])
-    Xty = jnp.sum(X * power[..., None], axis=-2)
+    Xty = _pairwise_sum(X * power[..., None], axis=-2)
     cx = _solve_spd(XtX, Xty)
     # back to usage coordinates: relu(x - q_k) = relu(u - b_k) / span
     coef = jnp.concatenate([cx[:1] - cx[1:2] * lo / span, cx[1:] / span])
@@ -125,7 +138,7 @@ def pd_slope(coef, breaks, u):
     """Local slope pi(u) = d power / d usage."""
     shp = u.shape
     uu = u.reshape(-1)
-    s = jnp.full_like(uu, coef[1])
+    s = jnp.broadcast_to(coef[1], uu.shape)
     for k in range(breaks.shape[0]):
         s = s + jnp.where(uu > breaks[k], coef[2 + k], 0.0)
     return s.reshape(shp)

@@ -743,6 +743,21 @@ def ones_xs(n_clusters: int, n_campuses: int, n_zones: int
 
 # ------------------------------------------------------------ init/burn-in
 
+def vary_like(tree, ref):
+    """Give a pytree built from constants the varying mesh axes of
+    ``ref``. Under ``shard_map`` a scan carry that starts from constants
+    is typed as the same on every device, while the day step makes it
+    vary with the device's slice of the batch; the carry's type must say
+    so up front. Outside ``shard_map`` this returns ``tree`` unchanged."""
+    vma = jax.typeof(ref).vma
+
+    def cast(x):
+        missing = tuple(sorted(vma - jax.typeof(x).vma))
+        return jax.lax.pcast(x, missing, to="varying") if missing else x
+
+    return jax.tree.map(cast, tree)
+
+
 def burnin_step(params: SimParams, state: SimState) -> SimState:
     """One unshaped day with the cheap linear power proxy (history fill)."""
     day_key = jax.random.fold_in(params.key, state.day)
@@ -814,6 +829,7 @@ def make_init(n_clusters: int, n_campuses: int, n_zones: int,
             observed_days=jnp.zeros((n,), jnp.int32),
             shaping_allowed=jnp.ones((n,), bool),
         )
+        state = vary_like(state, cap)
 
         def burn(s, _):
             return burnin_step(params, s), None

@@ -450,8 +450,10 @@ class HourAccum(NamedTuple):
     res: jnp.ndarray             # (n, 24) reservations = usage * ratio
 
 
-def hour_accum_init(n: int) -> HourAccum:
-    z24 = jnp.zeros((n, 24), f32)
+def hour_accum_init(u_if: jnp.ndarray) -> HourAccum:
+    """Empty accumulator for a day whose (n, 24) columns are shaped (and,
+    under ``shard_map``, typed) like ``u_if``."""
+    z24 = jnp.zeros_like(u_if)
     return HourAccum(hour=jnp.zeros((), jnp.int32), u_if=z24,
                      use_flex=z24, usage=z24, res=z24)
 

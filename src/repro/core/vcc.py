@@ -36,7 +36,6 @@ from repro.core import solver
 from repro.core.admission import hour_sum
 from repro.kernels.vcc_pgd import ref as _pgd_ref
 
-f32 = jnp.float32
 
 
 @dataclass(frozen=True)
@@ -232,13 +231,11 @@ def solve_vcc(p: VCCProblem, *, inner_iters: int = 80, outer_iters: int = 20,
     """
     if p.eta_ens is not None and p.eta_ens.shape[0] == 1:
         p = dataclasses.replace(p, eta_ens=None, pow_nom_ens=None)
-    n, H = p.eta.shape
     lo, ub, feasible = delta_bounds(p)
     # neutralize infeasible clusters: bounds collapse to {0}
     lo = jnp.where(feasible[:, None], lo, 0.0)
     ub = jnp.where(feasible[:, None], ub, 0.0)
     temp = solver.peak_temperature(p.pow_nom, temp_frac)
-    n_dc = p.campus_limit.shape[0]
     lr_eff = solver.scaled_lr(lr, p.pi, p.tau, p.eta, p.lambda_e,
                               p.lambda_p)
 
@@ -258,13 +255,14 @@ def solve_vcc(p: VCCProblem, *, inner_iters: int = 80, outer_iters: int = 20,
                     "step_max": jnp.abs(d_new - d_prev).max(axis=1)}
 
         delta, mu, traj = solver.dual_ascent(inner, dual_update,
-                                             jnp.zeros((n, H), f32),
-                                             jnp.zeros((n_dc,), f32),
+                                             jnp.zeros_like(p.eta),
+                                             jnp.zeros_like(p.campus_limit),
                                              outer_iters, diag_fn=diag_fn)
     else:
         delta, mu = solver.dual_ascent(inner, dual_update,
-                                       jnp.zeros((n, H), f32),
-                                       jnp.zeros((n_dc,), f32), outer_iters)
+                                       jnp.zeros_like(p.eta),
+                                       jnp.zeros_like(p.campus_limit),
+                                       outer_iters)
     pow_h = cluster_power(p, delta)
     y = pow_h.max(axis=1)
     vcc_shaped = (p.u_if + (1.0 + delta) * p.tau[:, None] / 24.0) * p.ratio

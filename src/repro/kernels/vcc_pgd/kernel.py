@@ -47,6 +47,15 @@ def _project_rows(z, lo, ub, proj_iters):
     return jnp.clip(z - nu[:, None], lo, ub)
 
 
+def _carry(x):
+    """``x`` as the start of a loop carry. Under ``shard_map`` Pallas types
+    a value loaded from a ref with the operands' varying mesh axes but
+    the result of arithmetic with none (jax 0.9), so a carry that starts
+    from a load would change type in the loop. Adding zero types it like
+    the loop's results, and leaves every value but -0.0 as it was."""
+    return x + 0.0
+
+
 def _pgd_kernel(delta_ref, eta_ref, pi_ref, pow_ref, tau_ref, price_ref,
                 lo_ref, ub_ref, lr_ref, temp_ref, lame_ref, out_ref, *,
                 iters, proj_iters):
@@ -71,7 +80,7 @@ def _pgd_kernel(delta_ref, eta_ref, pi_ref, pow_ref, tau_ref, price_ref,
         grad = (lambda_e * eta + price * w) * pi * tau24
         return _project_rows(d - lr * grad, lo, ub, proj_iters)
 
-    out_ref[...] = jax.lax.fori_loop(0, iters, body, delta).astype(
+    out_ref[...] = jax.lax.fori_loop(0, iters, body, _carry(delta)).astype(
         out_ref.dtype)
 
 
@@ -118,8 +127,16 @@ def _pgd_ens_kernel(delta_ref, eta_ref, pi_ref, pow_ref, tau_ref, price_ref,
         grad = (lambda_e * eta_w + price * w_w) * pi * tau24
         return _project_rows(d - lr * grad, lo, ub, proj_iters)
 
-    out_ref[...] = jax.lax.fori_loop(0, iters, body, delta).astype(
+    out_ref[...] = jax.lax.fori_loop(0, iters, body, _carry(delta)).astype(
         out_ref.dtype)
+
+
+def _out_shape(shape, dtype, args):
+    """A kernel output's type. Under ``shard_map`` it varies over every
+    mesh axis an operand varies over, which ``pallas_call`` cannot infer;
+    elsewhere that set is empty."""
+    vma = frozenset().union(*(jax.typeof(a).vma for a in args))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 def pgd_epoch_pallas(delta, eta, pi, pow_nom, tau24, price, lo, ub, lr, *,
@@ -151,7 +168,7 @@ def pgd_epoch_pallas(delta, eta, pi, pow_nom, tau24, price, lo, ub, lr, *,
         in_specs=[wide, wide, wide, wide, slim, slim, wide, wide, slim,
                   slim, slim],
         out_specs=wide,
-        out_shape=jax.ShapeDtypeStruct((n + pad, H), delta.dtype),
+        out_shape=_out_shape((n + pad, H), delta.dtype, args),
         interpret=interpret,
     )(*args)
     return out[:n]
@@ -241,8 +258,8 @@ def joint_step_pallas(delta, s, eta, pi, pow_nom, tau, u_if, u_if_q, ratio,
         in_specs=[wide, slim, wide, wide, wide, slim, wide, wide, wide,
                   slim, slim, slim, slim, slim, slim],
         out_specs=(wide, slim),
-        out_shape=(jax.ShapeDtypeStruct((n + pad, H), delta.dtype),
-                   jax.ShapeDtypeStruct((n + pad, 1), jnp.float32)),
+        out_shape=(_out_shape((n + pad, H), delta.dtype, args),
+                   _out_shape((n + pad, 1), jnp.float32, args)),
         interpret=interpret,
     )(*args)
     return d2[:n], g_s[:n]
@@ -291,7 +308,7 @@ def pgd_epoch_ens_pallas(delta, eta_e, pi, pow_nom_e, tau24, price, lo, ub,
         in_specs=[wide, ens, wide, ens, slim, slim, wide, wide, slim,
                   slim, slim, slim],
         out_specs=wide,
-        out_shape=jax.ShapeDtypeStruct((n + pad, H), delta.dtype),
+        out_shape=_out_shape((n + pad, H), delta.dtype, args),
         interpret=interpret,
     )(*args)
     return out[:n]

@@ -75,7 +75,7 @@ def test_hourly_chain_equals_daily_batch_update_bitwise(u_if, use_flex,
     fc = stats.streaming_forecast(pred, day, GAMMA)
     u_if, use_flex, ratio = map(jnp.asarray, (u_if, use_flex, ratio))
 
-    acc = stats.hour_accum_init(N)
+    acc = stats.hour_accum_init(u_if)
     upd = jax.jit(stats.hour_update)
     for h in range(24):
         acc = upd(acc, jnp.asarray(h, jnp.int32), u_if[:, h],

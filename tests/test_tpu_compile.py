@@ -4,7 +4,9 @@ No chip is needed: the TPU compiler compiles for a topology that is
 described and not attached, and refuses what Mosaic cannot lower (the
 interpret-mode tests cannot see that). Each kernel is compiled at the
 fleet size the chip smoke run uses, 256 clusters, and its HLO must call
-the kernel (``tpu_custom_call``). The topology is described inside a
+the kernel (``tpu_custom_call``). The sharded rollout is compiled over
+the described chip's 4 devices with the kernels on. The topology is
+described inside a
 module fixture, never at import: only one process at a time may load the
 TPU library, and under pytest-xdist each worker imports every test file.
 Keep every chip compile in this one file.
@@ -13,7 +15,9 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.vcc_pgd import kernel as K
@@ -23,7 +27,7 @@ f32 = jnp.float32
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -37,9 +41,14 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e):
+    return SingleDeviceSharding(v5e.devices[0])
 
 
 def _compile(fn, shapes, sharding):
@@ -71,4 +80,34 @@ def test_pgd_epoch_ens_compiles(one_chip, members):
     hlo = _compile(lambda *a: K.pgd_epoch_ens_pallas(
         *a, temp=0.5, lambda_e=1.0, risk_s=2.0, iters=80),
         [wide, ens, wide, ens, slim, slim, wide, wide, slim], one_chip)
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("flags", [{}, {"joint_spatial": True},
+                                   {"n_members": 8}],
+                         ids=["plain", "joint", "ensemble"])
+def test_sharded_rollout_compiles(v5e, monkeypatch, flags):
+    """``rollout_batch_sharded`` over the chip's 4 devices, with each
+    kernel on. ``shard_map`` types every value by the mesh axes it
+    varies over, and the kernel calls and the loops inside them must
+    keep that typing; the CPU tests run the jnp oracle and cannot see
+    it."""
+    from repro.kernels.vcc_pgd import ops
+    from repro.sim import (SimConfig, build_batch, default_library,
+                           mobility_sweep_library, risk_sweep_library,
+                           rollout_batch_sharded)
+    monkeypatch.setattr(ops, "tpu_available", lambda: True)
+    days = 2
+    cfg = SimConfig(n_clusters=8, n_campuses=2, n_zones=2, hist_days=14,
+                    **flags)
+    lib = (mobility_sweep_library if cfg.joint_spatial else
+           risk_sweep_library if cfg.n_members > 1 else default_library)
+    batch = build_batch(cfg, lib(days)[:2], [0, 1], days)
+    mesh = Mesh(np.array(v5e.devices), ("batch",))
+    spec = NamedSharding(mesh, P("batch"))
+    sds = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=spec),
+        batch)
+    hlo = jax.jit(rollout_batch_sharded(cfg, days, mesh)).lower(
+        sds).compile().as_text()
     assert "tpu_custom_call" in hlo
