@@ -38,8 +38,7 @@ contract), or if the telemetry-on rollout costs >= 15% over the
 telemetry-off rollout — the regression tripwires the CI workflow runs
 on every push. Every failed gate prints the measured value against the
 gate threshold. Quick mode also exports the telemetry JSONL trace
-(TELEMETRY_trace.jsonl next to the --out json) and per-stage cost rows
-(``stage_costs`` in the json) — the CI artifacts.
+(TELEMETRY_trace.jsonl next to the --out json) — the CI artifact.
 """
 from __future__ import annotations
 
@@ -67,7 +66,6 @@ from repro.sim import (SimConfig, Scenario, build_batch, build_params,
                        risk_sweep_rows, rollout_batch,
                        rollout_batch_sharded, scenario_rows, state_nbytes,
                        telemetry_records, write_jsonl)
-from repro.sim import telemetry as telemetry_mod
 from repro.sim.engine import _day_xs
 
 BENCH_PATH = Path(__file__).resolve().parents[1] / "BENCH_sim.json"
@@ -368,15 +366,14 @@ def _legacy_dual_ascent(inner, dual_update, x0, mu0, outer_iters):
 
 def _telemetry_probe(n_clusters=6, days=4, n_scen=2, n_seeds=2,
                      hist_days=14, reps=3):
-    """Telemetry collapse + overhead + stage-cost attribution probe.
+    """Telemetry collapse + overhead probe.
 
     Times the SAME (scenario x seed) batch rollout with telemetry off and
     on (steady state, best-of-``reps``) -> ``telemetry_overhead_pct``
     (CI gate: < 15%); byte-compares the telemetry-off day-step HLO
     against the graph traced with the pre-telemetry dual-ascent scan ->
-    ``telemetry_hlo_identical`` (CI gate: must hold); profiles per-stage
-    compiled cost (``sim.telemetry.profile_stages``) -> ``stage_costs``
-    rows; and returns the exported JSONL trace records."""
+    ``telemetry_hlo_identical`` (CI gate: must hold); and returns the
+    exported JSONL trace records."""
     base = dict(n_clusters=n_clusters, n_campuses=2, n_zones=2,
                 pds_per_cluster=2, hist_days=hist_days)
     cfg_off = SimConfig(**base)
@@ -416,7 +413,6 @@ def _telemetry_probe(n_clusters=6, days=4, n_scen=2, n_seeds=2,
         solver.dual_ascent = orig
         stages_mod.jitted_day_step.cache_clear()
 
-    stage_costs = telemetry_mod.profile_stages(scfg, p1, s1, reps=reps)
     records = telemetry_records(traj["telemetry"],
                                 [s.name for s in scens], n_seeds)
     return {
@@ -424,7 +420,6 @@ def _telemetry_probe(n_clusters=6, days=4, n_scen=2, n_seeds=2,
         "telemetry_rollout_on_s": t_on,
         "telemetry_overhead_pct": 100.0 * (t_on / t_off - 1.0),
         "telemetry_hlo_identical": bool(hlo_off == hlo_legacy),
-        "stage_costs": stage_costs,
     }, records
 
 
@@ -675,10 +670,6 @@ def run(quick: bool = False, out_path: Path = None):
          "mpc-off day-step HLO vs the pre-MPC open-loop graph; "
          "1.0 = byte-identical (collapse contract)"),
     ]
-    for r in tel["stage_costs"]:
-        out.append((f"sim_stagecost_{r['stage']}_ms", r["wall_ms"],
-                    f"{r['pct']:.1f}% of summed stage wall time "
-                    f"(dot {r['dot_flops'] / 1e9:.3f} GFLOP)"))
     for r in hor_rows:
         out.append((f"sim_{r['mode']}_days_per_sec_h{r['horizon_days']}",
                     r["days_per_sec"],

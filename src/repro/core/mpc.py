@@ -111,6 +111,7 @@ def mpc_day(prob: vcc.VCCProblem, sol: vcc.VCCSolution, tuf_fc, gate,
     )
     xs = (jnp.arange(24), u_if.T, arrivals.T, ratio_true.T, intensity.T)
 
+    @jax.named_scope("mpc.hour")
     def hour_step(c, x):
         h, uif_h, arr_h, r_h, eta_h = x
         # 1. enforce the current plan's curve for this hour
@@ -158,10 +159,11 @@ def mpc_day(prob: vcc.VCCProblem, sol: vcc.VCCSolution, tuf_fc, gate,
         scale = (c["tau"] / jnp.clip(tau_new, 1e-9, None))[:, None]
         delta_warm = jnp.where(rem, (1.0 + c["delta"]) * scale - 1.0,
                                pinned)
-        sol_s = vcc.solve_vcc_suffix(
-            p_now, delta_warm, c["mu"], h + 1, inner_iters=inner_iters,
-            outer_iters=outer_iters, use_pallas=use_pallas,
-            interpret=interpret)
+        with jax.named_scope("mpc.resolve"):
+            sol_s = vcc.solve_vcc_suffix(
+                p_now, delta_warm, c["mu"], h + 1, inner_iters=inner_iters,
+                outer_iters=outer_iters, use_pallas=use_pallas,
+                interpret=interpret)
         accept = gate & trigger & sol_s.shaped
         delta_next = jnp.where(accept[:, None], sol_s.delta, c["delta"])
         tau_next = jnp.where(accept, tau_new, c["tau"])

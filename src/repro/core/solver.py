@@ -121,8 +121,12 @@ def dual_ascent(inner, dual_update, x0, mu0, outer_iters: int,
     def outer(carry, _):
         x, mu = carry
         x_new = inner(x, mu)
-        mu = dual_update(x_new, mu)
-        y = None if diag_fn is None else diag_fn(x, x_new, mu)
+        with jax.named_scope("solver.dual_update"):
+            mu = dual_update(x_new, mu)
+        y = None
+        if diag_fn is not None:
+            with jax.named_scope("solver.diagnostics"):
+                y = diag_fn(x, x_new, mu)
         return (x_new, mu), y
 
     (x, mu), ys = jax.lax.scan(outer, (x0, mu0), None, length=outer_iters)
@@ -142,8 +146,9 @@ def pgd_epochs(prob, delta, mu, lo, ub, lr_eff, temp, iters: int, *,
     Pallas interpreter (CPU tests). Problems carrying ensemble axes route
     to the CVaR member-reduction epoch."""
     from repro.kernels.vcc_pgd import ops as _k
-    return _k.pgd_epoch(prob, delta, mu, lo, ub, lr_eff, temp, iters,
-                        use_pallas=use_pallas, interpret=interpret)
+    with jax.named_scope("solver.pgd_epoch"):
+        return _k.pgd_epoch(prob, delta, mu, lo, ub, lr_eff, temp, iters,
+                            use_pallas=use_pallas, interpret=interpret)
 
 
 def joint_epochs(prob, delta, s, mu, lo_s, ub_s, lr_d, lr_s, temp,
@@ -160,8 +165,10 @@ def joint_epochs(prob, delta, s, mu, lo_s, ub_s, lr_d, lr_s, temp,
 
     def body(i, carry):
         d, sv = carry
-        d, g_s = _k.joint_step(prob, d, sv, mu, lr_d, temp,
-                               use_pallas=use_pallas, interpret=interpret)
+        with jax.named_scope("solver.pgd_epoch"):
+            d, g_s = _k.joint_step(prob, d, sv, mu, lr_d, temp,
+                                   use_pallas=use_pallas,
+                                   interpret=interpret)
         z = sv - lr_s * g_s[:, 0]
         sv = project_conservation(z[None, :], lo_s[None, :],
                                   ub_s[None, :])[0]

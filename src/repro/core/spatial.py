@@ -231,11 +231,12 @@ def solve_joint(p: VCCProblem, mobility, *, inner_iters: int = 80,
     sol = VCCSolution(delta=delta, y=y, vcc=vcc_curve, shaped=feasible,
                       mu=mu, objective=joint_objective(p, delta, s, mu))
     if telemetry:
-        diag = {"obj_cluster_traj": diag_seq["obj_cluster_traj"],
-                "step_max_traj": diag_seq["step_max_traj"],
-                **vcc.solution_diagnostics(pf, delta, mu,
-                                           temp_frac=temp_frac),
-                "joint_winner": take.astype(f32)}
+        with jax.named_scope("solver.diagnostics"):
+            diag = {"obj_cluster_traj": diag_seq["obj_cluster_traj"],
+                    "step_max_traj": diag_seq["step_max_traj"],
+                    **vcc.solution_diagnostics(pf, delta, mu,
+                                               temp_frac=temp_frac),
+                    "joint_winner": take.astype(f32)}
         return sol, tau_j, s, diag
     return sol, tau_j, s
 

@@ -431,22 +431,25 @@ def optimize_stage(cfg: StageConfig, fc, eta_fc, model: PowerModel, queue,
     (``vcc.solve_vcc(..., telemetry=True)`` channels + ``joint_winner``)
     when ``cfg.telemetry``, else ``None`` — and the telemetry=False path
     calls the solvers EXACTLY as before (byte-identical graph)."""
-    prob = build_problem_arrays(
-        fc, eta_fc,
-        lambda u: model_power(model, u), lambda u: model_slope(model, u),
-        queue, u_pow_cap, cap_day, campus, campus_limit, lambda_e, lambda_p)
-    prob = jax.lax.optimization_barrier(prob)
+    with jax.named_scope("solver.problem"):
+        prob = build_problem_arrays(
+            fc, eta_fc,
+            lambda u: model_power(model, u), lambda u: model_slope(model, u),
+            queue, u_pow_cap, cap_day, campus, campus_limit, lambda_e,
+            lambda_p)
+        prob = jax.lax.optimization_barrier(prob)
     diag = None
     if cfg.joint_spatial:
-        if cfg.telemetry:
-            sol, tau_j, _, diag = spatial.solve_joint(
-                prob, mobility, use_pallas=cfg.use_pallas,
-                interpret=cfg.interpret, telemetry=True)
-        else:
-            sol, tau_j, _ = spatial.solve_joint(prob, mobility,
-                                                use_pallas=cfg.use_pallas,
-                                                interpret=cfg.interpret)
-        sol, tau_j = jax.lax.optimization_barrier((sol, tau_j))
+        with jax.named_scope("solver.spatial"):
+            if cfg.telemetry:
+                sol, tau_j, _, diag = spatial.solve_joint(
+                    prob, mobility, use_pallas=cfg.use_pallas,
+                    interpret=cfg.interpret, telemetry=True)
+            else:
+                sol, tau_j, _ = spatial.solve_joint(
+                    prob, mobility, use_pallas=cfg.use_pallas,
+                    interpret=cfg.interpret)
+            sol, tau_j = jax.lax.optimization_barrier((sol, tau_j))
         prob = dataclasses.replace(prob, tau=tau_j)
         if ens is not None:
             prob = risk.attach_ensemble(prob, **ens)
@@ -463,8 +466,9 @@ def optimize_stage(cfg: StageConfig, fc, eta_fc, model: PowerModel, queue,
         if diag is not None:
             diag = jax.lax.optimization_barrier(diag)
         return prob, sol, diag
-    tau_shifted, _ = spatial.spatial_shift(prob, mobility=mobility)
-    tau_shifted = jax.lax.optimization_barrier(tau_shifted)
+    with jax.named_scope("solver.spatial"):
+        tau_shifted, _ = spatial.spatial_shift(prob, mobility=mobility)
+        tau_shifted = jax.lax.optimization_barrier(tau_shifted)
     prob = dataclasses.replace(prob, tau=tau_shifted)
     if ens is not None:
         prob = risk.attach_ensemble(prob, **ens)
@@ -586,49 +590,57 @@ def make_day_step(cfg: StageConfig):
         # over the PredictorState carry (the usage ring IS the 28-day
         # window the rescan power fit slices, so the fit is bitwise the
         # same); rescan: the legacy O(H) history-window graph.
-        if cfg.streaming:
-            model = power_stage(state.pred.usage_ring, params.lam,
+        usage_window = (state.pred.usage_ring if cfg.streaming
+                        else state.hist_usage)
+        with jax.named_scope("stage.power"):
+            model = power_stage(usage_window, params.lam,
                                 params.truth["capacity"], pd_truth(params),
                                 jax.random.fold_in(day_key, 1))
-            fc = forecast_stage_streaming(state.pred, state.day,
-                                          params.gamma)
-        else:
-            model = power_stage(state.hist_usage, params.lam,
-                                params.truth["capacity"], pd_truth(params),
-                                jax.random.fold_in(day_key, 1))
-            fc = forecast_stage(
-                state.hist_uif, state.hist_flex_daily, state.hist_res_daily,
-                state.hist_usage, state.hist_res, state.hist_tr_pred,
-                state.hist_uif_pred, state.day, params.gamma)
+        with jax.named_scope("stage.forecast"):
+            if cfg.streaming:
+                fc = forecast_stage_streaming(state.pred, state.day,
+                                              params.gamma)
+            else:
+                fc = forecast_stage(
+                    state.hist_uif, state.hist_flex_daily,
+                    state.hist_res_daily, state.hist_usage, state.hist_res,
+                    state.hist_tr_pred, state.hist_uif_pred, state.day,
+                    params.gamma)
         # 3. carbon pipeline: scenario-perturbed grid, day-ahead forecast
-        act_z, fc_z = carbon_stage(params.zone, state.carbon_hist,
-                                   jax.random.fold_in(day_key, 4),
-                                   xs["green_scale"], xs["coal_scale"])
-        # intraday forecast-busting: perturb the ACTUAL intensity after
-        # the day-ahead forecast is drawn (the planner is blind until the
-        # hours realize; tomorrow's forecaster sees them via carbon_hist)
-        if "carbon_hour_scale" in xs:
-            act_z = act_z * xs["carbon_hour_scale"][None, :]
-        eta_act = act_z[state.zmap]
-        eta_fc = fc_z[state.zmap]
+        with jax.named_scope("stage.carbon"):
+            act_z, fc_z = carbon_stage(params.zone, state.carbon_hist,
+                                       jax.random.fold_in(day_key, 4),
+                                       xs["green_scale"], xs["coal_scale"])
+            # intraday forecast-busting: perturb the ACTUAL intensity
+            # after the day-ahead forecast is drawn (the planner is blind
+            # until the hours realize; tomorrow's forecaster sees them via
+            # carbon_hist)
+            if "carbon_hour_scale" in xs:
+                act_z = act_z * xs["carbon_hour_scale"][None, :]
+            eta_act = act_z[state.zmap]
+            eta_fc = fc_z[state.zmap]
         # 3b. forecast ensembles (K > 1 only: the n_members == 1 graph must
         # stay identical to the point-forecast day — parity/golden traces)
         ens = None
         if cfg.n_members > 1:
-            ens = risk.day_ensembles(
-                jax.random.fold_in(day_key, 5), cfg.n_members, fc["uif"],
-                state.hist_uif_pred, state.hist_uif, fc_z,
-                state.carbon_hist, state.zmap, params.risk_beta)
+            with jax.named_scope("stage.ensembles"):
+                ens = risk.day_ensembles(
+                    jax.random.fold_in(day_key, 5), cfg.n_members,
+                    fc["uif"], state.hist_uif_pred, state.hist_uif, fc_z,
+                    state.carbon_hist, state.zmap, params.risk_beta)
         # 4. fleetwide risk-aware VCC optimization (+ spatial pre-shift)
-        prob, sol, sdiag = optimize_stage(
-            cfg, fc, eta_fc, model, state.queue,
-            state.u_pow_cap * xs["cap_scale"], cap_day, state.campus,
-            state.campus_limit * xs["campus_scale"],
-            params.lambda_e, params.lambda_p, params.mobility, ens=ens)
+        with jax.named_scope("stage.optimize"):
+            prob, sol, sdiag = optimize_stage(
+                cfg, fc, eta_fc, model, state.queue,
+                state.u_pow_cap * xs["cap_scale"], cap_day, state.campus,
+                state.campus_limit * xs["campus_scale"],
+                params.lambda_e, params.lambda_p, params.mobility, ens=ens)
         # 5. SLO gate: paused clusters get VCC = machine capacity
-        gate = state.shaping_allowed & sol.shaped
-        vcc_curve = jnp.where(gate[:, None], sol.vcc, cap_day[:, None] * 10.0)
-        vcc_curve = jax.lax.optimization_barrier(vcc_curve)
+        with jax.named_scope("stage.slo"):
+            gate = state.shaping_allowed & sol.shaped
+            vcc_curve = jnp.where(gate[:, None], sol.vcc,
+                                  cap_day[:, None] * 10.0)
+            vcc_curve = jax.lax.optimization_barrier(vcc_curve)
         # 6. real time: admission on ACTUAL load (+ counterfactual).
         # mpc=True runs the hourly recourse loop and the curve the SLO
         # detector sees is the hour-by-hour ENFORCED one, not the 00:00
@@ -636,86 +648,95 @@ def make_day_step(cfg: StageConfig):
         arr_hs = xs.get("arrival_hour_scale")
         mdiag = None
         acc = None
-        if cfg.mpc:
-            res, cf, u_if, _, vcc_enforced, acc, mdiag = observe_stage_mpc(
-                params.truth, state.day, day_key, prob, sol, fc, gate,
-                cap_day, xs["arrival_scale"], state.queue, state.cf_queue,
-                lambda u: model_power(model, u), eta_act,
-                allowance_frac=cfg.slo_allowance, arr_hour_scale=arr_hs,
-                use_pallas=cfg.use_pallas, interpret=cfg.interpret)
-        else:
-            res, cf, u_if, _ = observe_stage(
-                params.truth, state.day, day_key, vcc_curve, cap_day,
-                xs["arrival_scale"], state.queue, state.cf_queue,
-                lambda u: model_power(model, u), eta_act,
-                allowance_frac=cfg.slo_allowance, arr_hour_scale=arr_hs)
-            vcc_enforced = vcc_curve
-        # 7. telemetry + SLO feedback
-        slo_state = {"crowded_streak": state.crowded_streak,
-                     "pause_left": state.pause_left,
-                     "violation_days": state.violation_days,
-                     "observed_days": state.observed_days}
-        new_slo, allowed = slo_stage(slo_state, slo_cfg,
-                                     hour_sum(res.reservations),
-                                     hour_sum(vcc_enforced), res.unmet,
-                                     res.arrived)
-        if cfg.streaming:
-            # O(1) telemetry: absorb the day into the streaming carry
-            # (prediction errors pair same-day with the fc issued above —
-            # exactly what the hist_*_pred rolls recorded for later)
+        with jax.named_scope("stage.observe"):
             if cfg.mpc:
-                # hour-grain chain: the 24 hour_update scatters finalize
-                # into the same PredictorState the daily batch would
-                pred_new = stats.hour_finalize(state.pred, acc, fc,
-                                               state.day, params.gamma)
+                (res, cf, u_if, _, vcc_enforced, acc,
+                 mdiag) = observe_stage_mpc(
+                    params.truth, state.day, day_key, prob, sol, fc, gate,
+                    cap_day, xs["arrival_scale"], state.queue,
+                    state.cf_queue, lambda u: model_power(model, u),
+                    eta_act, allowance_frac=cfg.slo_allowance,
+                    arr_hour_scale=arr_hs, use_pallas=cfg.use_pallas,
+                    interpret=cfg.interpret)
             else:
-                pred_new = stats.predictor_update(
-                    state.pred, fc, state.day, params.gamma, u_if,
-                    res.served, hour_sum(res.reservations),
-                    res.usage_total, res.reservations)
-            telemetry = dict(pred=pred_new)
-        else:
-            # roll the rescan history windows (predictions included, for
-            # the trailing-error quantiles)
-            telemetry = dict(
-                hist_uif=roll(state.hist_uif, u_if),
-                hist_flex_daily=roll(state.hist_flex_daily, res.served),
-                hist_res_daily=roll(state.hist_res_daily,
-                                    hour_sum(res.reservations)),
-                hist_usage=roll(state.hist_usage, res.usage_total),
-                hist_res=roll(state.hist_res, res.reservations),
-                hist_tr_pred=roll(state.hist_tr_pred, fc["tr"]),
-                hist_uif_pred=roll(state.hist_uif_pred, fc["uif"]))
-        new_state = state._replace(
-            day=state.day + 1,
-            carbon_hist=roll(state.carbon_hist, act_z),
-            queue=res.queue_end,
-            cf_queue=cf.queue_end,
-            crowded_streak=new_slo["crowded_streak"],
-            pause_left=new_slo["pause_left"],
-            violation_days=new_slo["violation_days"],
-            observed_days=new_slo["observed_days"],
-            shaping_allowed=allowed,
-            **telemetry,
-        )
+                res, cf, u_if, _ = observe_stage(
+                    params.truth, state.day, day_key, vcc_curve, cap_day,
+                    xs["arrival_scale"], state.queue, state.cf_queue,
+                    lambda u: model_power(model, u), eta_act,
+                    allowance_frac=cfg.slo_allowance,
+                    arr_hour_scale=arr_hs)
+                vcc_enforced = vcc_curve
+        # 7. telemetry + SLO feedback
+        with jax.named_scope("stage.slo"):
+            slo_state = {"crowded_streak": state.crowded_streak,
+                         "pause_left": state.pause_left,
+                         "violation_days": state.violation_days,
+                         "observed_days": state.observed_days}
+            new_slo, allowed = slo_stage(slo_state, slo_cfg,
+                                         hour_sum(res.reservations),
+                                         hour_sum(vcc_enforced), res.unmet,
+                                         res.arrived)
+        with jax.named_scope("stage.history"):
+            if cfg.streaming:
+                # O(1) telemetry: absorb the day into the streaming carry
+                # (prediction errors pair same-day with the fc issued
+                # above — exactly what the hist_*_pred rolls recorded for
+                # later)
+                if cfg.mpc:
+                    # hour-grain chain: the 24 hour_update scatters
+                    # finalize into the same PredictorState the daily
+                    # batch would
+                    pred_new = stats.hour_finalize(state.pred, acc, fc,
+                                                   state.day, params.gamma)
+                else:
+                    pred_new = stats.predictor_update(
+                        state.pred, fc, state.day, params.gamma, u_if,
+                        res.served, hour_sum(res.reservations),
+                        res.usage_total, res.reservations)
+                telemetry = dict(pred=pred_new)
+            else:
+                # roll the rescan history windows (predictions included,
+                # for the trailing-error quantiles)
+                telemetry = dict(
+                    hist_uif=roll(state.hist_uif, u_if),
+                    hist_flex_daily=roll(state.hist_flex_daily, res.served),
+                    hist_res_daily=roll(state.hist_res_daily,
+                                        hour_sum(res.reservations)),
+                    hist_usage=roll(state.hist_usage, res.usage_total),
+                    hist_res=roll(state.hist_res, res.reservations),
+                    hist_tr_pred=roll(state.hist_tr_pred, fc["tr"]),
+                    hist_uif_pred=roll(state.hist_uif_pred, fc["uif"]))
+            new_state = state._replace(
+                day=state.day + 1,
+                carbon_hist=roll(state.carbon_hist, act_z),
+                queue=res.queue_end,
+                cf_queue=cf.queue_end,
+                crowded_streak=new_slo["crowded_streak"],
+                pause_left=new_slo["pause_left"],
+                violation_days=new_slo["violation_days"],
+                observed_days=new_slo["observed_days"],
+                shaping_allowed=allowed,
+                **telemetry,
+            )
         # 8. DayTelemetry record (telemetry=False leaves the default None
         # StepOut leaf -> empty pytree subtree -> unchanged compiled graph)
         telem = None
         if cfg.telemetry:
             # lazy: core must not import repro.sim at module level
             from repro.sim import telemetry as _telemetry
-            if cfg.streaming:
-                trail = {"uif": state.pred.uif_day_ring,
-                         "tuf": state.pred.flex_ring,
-                         "tr": state.pred.res_ring}
-            else:
-                trail = {"uif": hour_sum(state.hist_uif[:, -7:]),
-                         "tuf": state.hist_flex_daily[:, -7:],
-                         "tr": state.hist_res_daily[:, -7:]}
-            telem = _telemetry.day_telemetry(
-                sdiag, fc, res, u_if, vcc_enforced,
-                pause_left=new_slo["pause_left"], shaped=sol.shaped,
-                trail=trail, recourse=mdiag)
+            with jax.named_scope("stage.telemetry"):
+                if cfg.streaming:
+                    trail = {"uif": state.pred.uif_day_ring,
+                             "tuf": state.pred.flex_ring,
+                             "tr": state.pred.res_ring}
+                else:
+                    trail = {"uif": hour_sum(state.hist_uif[:, -7:]),
+                             "tuf": state.hist_flex_daily[:, -7:],
+                             "tr": state.hist_res_daily[:, -7:]}
+                telem = _telemetry.day_telemetry(
+                    sdiag, fc, res, u_if, vcc_enforced,
+                    pause_left=new_slo["pause_left"], shaped=sol.shaped,
+                    trail=trail, recourse=mdiag)
         return new_state, StepOut(res=res, cf=cf, sol=sol,
                                   vcc_curve=vcc_enforced, fc=fc, prob=prob,
                                   eta_act=eta_act, telemetry=telem)
@@ -766,15 +787,17 @@ def burnin_step(params: SimParams, state: SimState) -> SimState:
     def proxy_power(u):
         return 100.0 + 300.0 * u
 
-    act_z, _ = carbon_stage(params.zone, state.carbon_hist,
-                            jax.random.fold_in(day_key, 4),
-                            jnp.ones_like(params.zone["solar_cap"]),
-                            jnp.ones_like(params.zone["solar_cap"]))
-    unshaped = jnp.broadcast_to(cap[:, None] * 10.0, (cap.shape[0], 24))
-    res, _, u_if, _ = observe_stage(
-        params.truth, state.day, day_key, unshaped, cap,
-        jnp.ones_like(cap), state.queue, state.queue, proxy_power,
-        act_z[state.zmap])
+    with jax.named_scope("stage.carbon"):
+        act_z, _ = carbon_stage(params.zone, state.carbon_hist,
+                                jax.random.fold_in(day_key, 4),
+                                jnp.ones_like(params.zone["solar_cap"]),
+                                jnp.ones_like(params.zone["solar_cap"]))
+    with jax.named_scope("stage.observe"):
+        unshaped = jnp.broadcast_to(cap[:, None] * 10.0, (cap.shape[0], 24))
+        res, _, u_if, _ = observe_stage(
+            params.truth, state.day, day_key, unshaped, cap,
+            jnp.ones_like(cap), state.queue, state.queue, proxy_power,
+            act_z[state.zmap])
     return state._replace(
         day=state.day + 1,
         hist_uif=roll(state.hist_uif, u_if),
@@ -805,6 +828,7 @@ def make_init(n_clusters: int, n_campuses: int, n_zones: int,
     campus_np = [i % m for i in range(n)]
     zmap_np = [(c % z) for c in campus_np]
 
+    @jax.named_scope("engine.burnin")
     def init(params: SimParams) -> SimState:
         cap = params.truth["capacity"]
         state = SimState(

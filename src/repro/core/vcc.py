@@ -273,9 +273,10 @@ def solve_vcc(p: VCCProblem, *, inner_iters: int = 80, outer_iters: int = 20,
                       objective=objective(p, delta, mu, risk=False))
     if not telemetry:
         return sol
-    diag = {"obj_cluster_traj": traj["obj_cluster"],
-            "step_max_traj": traj["step_max"],
-            **solution_diagnostics(p, delta, mu, temp_frac=temp_frac)}
+    with jax.named_scope("solver.diagnostics"):
+        diag = {"obj_cluster_traj": traj["obj_cluster"],
+                "step_max_traj": traj["step_max"],
+                **solution_diagnostics(p, delta, mu, temp_frac=temp_frac)}
     return sol, diag
 
 

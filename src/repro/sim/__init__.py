@@ -24,7 +24,6 @@ from repro.sim.report import (scenario_rows, format_table,
                               TELEMETRY_COLUMNS)
 from repro.sim.telemetry import (DayTelemetry, day_telemetry,
                                  telemetry_records, write_jsonl, read_jsonl,
-                                 profile_stages, format_stage_table,
                                  TRACE_FIELDS)
 
 __all__ = [
@@ -40,5 +39,5 @@ __all__ = [
     "telemetry_rows", "MOBILITY_COLUMNS", "MPC_COLUMNS", "RISK_COLUMNS",
     "TELEMETRY_COLUMNS",
     "DayTelemetry", "day_telemetry", "telemetry_records", "write_jsonl",
-    "read_jsonl", "profile_stages", "format_stage_table", "TRACE_FIELDS",
+    "read_jsonl", "TRACE_FIELDS",
 ]

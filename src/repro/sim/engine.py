@@ -165,13 +165,14 @@ def make_rollout(cfg: SimConfig, days: int):
         def body(carry, xs):
             s, led = carry
             s, out = step(params, s, xs)
-            metrics = _metrics(out.res, out.cf)
-            led = ledger_update(led, metrics)
-            traj = {"carbon_kg": _hsum(metrics.carbon_kg),
-                    "cf_carbon_kg": _hsum(metrics.cf_carbon_kg),
-                    "kwh": _hsum(metrics.kwh),
-                    "peak_kw": _hsum(metrics.peak_kw),
-                    "queue": _hsum(metrics.queue_end)}
+            with jax.named_scope("engine.ledger"):
+                metrics = _metrics(out.res, out.cf)
+                led = ledger_update(led, metrics)
+                traj = {"carbon_kg": _hsum(metrics.carbon_kg),
+                        "cf_carbon_kg": _hsum(metrics.cf_carbon_kg),
+                        "kwh": _hsum(metrics.kwh),
+                        "peak_kw": _hsum(metrics.peak_kw),
+                        "queue": _hsum(metrics.queue_end)}
             if cfg.telemetry:
                 # stacked by the scan -> (days, ...) DayTelemetry leaves
                 # (telemetry=False keeps the traj keys — and graph —

@@ -1,6 +1,6 @@
 """Fleet telemetry layer: in-scan diagnostics + host-side trace export.
 
-The measurement plane of the reproduction — three surfaces:
+The measurement plane of the reproduction — two surfaces:
 
 * **In-graph** (`DayTelemetry`, `day_telemetry`): a pytree record built
   inside the jitted day step when ``StageConfig.telemetry=True``. Solver
@@ -24,23 +24,19 @@ The measurement plane of the reproduction — three surfaces:
   per scenario x seed x day (cluster axes reduced host-side), the schema
   consumed by ``report.telemetry_rows`` and the CI trace artifact.
 
-* **Stage cost attribution** (`profile_stages`, `format_stage_table`):
-  host-side profiler that compiles each stage standalone, reads static
-  compiled cost from the HLO text (``launch.hlo_analysis.analyze_hlo``)
-  and attributes wall-clock (best-of-reps, ``block_until_ready``) per
-  stage against the full jitted day step.
+Device time per stage is not measured here: the day step names its
+stages with ``jax.named_scope`` and a profiler trace of the fused program
+is read by those names (``benchmarks/chip/scopes.py``).
 """
 from __future__ import annotations
 
 import json
-import time
 from typing import Dict, List, NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import stages
 from repro.core.admission import hour_sum
 
 f32 = jnp.float32
@@ -255,147 +251,8 @@ def read_jsonl(path) -> List[Dict[str, object]]:
         return [json.loads(line) for line in f if line.strip()]
 
 
-# --------------------------------------------------- stage cost attribution
-
-def _time_compiled(fn, args, reps: int):
-    """(compiled HLO text, best-of-reps wall seconds) of jit(fn)(*args)."""
-    f = jax.jit(fn)
-    text = f.lower(*args).compile().as_text()
-    out = f(*args)                      # warm-up (compile + first run)
-    jax.block_until_ready(out)
-    best = float("inf")
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        jax.block_until_ready(f(*args))
-        best = min(best, time.perf_counter() - t0)
-    return text, best
-
-
-def profile_stages(cfg: stages.StageConfig, params, state,
-                   reps: int = 3) -> List[Dict[str, object]]:
-    """Attribute compiled cost per stage of the day cycle.
-
-    Compiles each stage standalone at the shapes of ``(params, state)``
-    (a burned-in SimState), reads static dot FLOPs/bytes from the
-    compiled HLO (``launch.hlo_analysis.analyze_hlo`` — while-loop trip
-    counts multiplied through, so the PGD scan is costed per-iteration),
-    and times best-of-``reps`` wall clock with ``block_until_ready``.
-    Returns rows {stage, wall_ms, pct, dot_flops, dot_bytes}; ``pct`` is
-    the share of summed per-stage wall time, plus a final ``day_step``
-    row timing the full fused step (its wall_ms < the stage sum is the
-    fusion win; pct is relative to the same stage sum)."""
-    from repro.launch.hlo_analysis import analyze_hlo
-
-    n = state.queue.shape[0]
-    m = state.campus_limit.shape[0]
-    z = state.carbon_hist.shape[0]
-    xs = stages.ones_xs(n, m, z)
-    day_key = jax.random.fold_in(params.key, state.day)
-    pdt = stages.pd_truth(params)
-    cap = params.truth["capacity"]
-    hist_usage = state.pred.usage_ring if cfg.streaming else state.hist_usage
-
-    def power_fn(hist, key):
-        return stages.power_stage(hist, params.lam, cap, pdt, key)
-
-    if cfg.streaming:
-        def forecast_fn(day, gamma):
-            return stages.forecast_stage_streaming(state.pred, day, gamma)
-        forecast_args = (state.day, params.gamma)
-    else:
-        forecast_fn = stages.forecast_stage
-        forecast_args = (state.hist_uif, state.hist_flex_daily,
-                         state.hist_res_daily, state.hist_usage,
-                         state.hist_res, state.hist_tr_pred,
-                         state.hist_uif_pred, state.day, params.gamma)
-
-    def carbon_fn(hist, key):
-        return stages.carbon_stage(params.zone, hist, key,
-                                   xs["green_scale"], xs["coal_scale"])
-
-    # eager prerequisites for the downstream stages
-    model = power_fn(hist_usage, jax.random.fold_in(day_key, 1))
-    fc = forecast_fn(*forecast_args)
-    act_z, fc_z = carbon_fn(state.carbon_hist,
-                            jax.random.fold_in(day_key, 4))
-    eta_act, eta_fc = act_z[state.zmap], fc_z[state.zmap]
-    ens = None
-    if cfg.n_members > 1:
-        from repro.core import risk
-        ens = risk.day_ensembles(
-            jax.random.fold_in(day_key, 5), cfg.n_members, fc["uif"],
-            state.hist_uif_pred, state.hist_uif, fc_z, state.carbon_hist,
-            state.zmap, params.risk_beta)
-
-    def optimize_fn(fcv, eta, queue, u_pow_cap, cap_day, campus_limit):
-        return stages.optimize_stage(
-            cfg, fcv, eta, model, queue, u_pow_cap, cap_day, state.campus,
-            campus_limit, params.lambda_e, params.lambda_p,
-            params.mobility, ens=ens)
-
-    _, sol, _ = optimize_fn(fc, eta_fc, state.queue, state.u_pow_cap, cap,
-                            state.campus_limit)
-    gate = state.shaping_allowed & sol.shaped
-    vcc_curve = jnp.where(gate[:, None], sol.vcc, cap[:, None] * 10.0)
-
-    def observe_fn(curve, cap_day, queue, cf_queue, eta):
-        return stages.observe_stage(
-            params.truth, state.day, day_key, curve, cap_day,
-            xs["arrival_scale"], queue, cf_queue,
-            lambda u: stages.model_power(model, u), eta)
-
-    entries = [
-        ("power_fit", power_fn,
-         (hist_usage, jax.random.fold_in(day_key, 1))),
-        ("forecast", forecast_fn, forecast_args),
-        ("carbon", carbon_fn,
-         (state.carbon_hist, jax.random.fold_in(day_key, 4))),
-        ("optimize", optimize_fn,
-         (fc, eta_fc, state.queue, state.u_pow_cap, cap,
-          state.campus_limit)),
-        ("observe", observe_fn,
-         (vcc_curve, cap, state.queue, state.cf_queue, eta_act)),
-    ]
-    rows: List[Dict[str, object]] = []
-    for name, fn, args in entries:
-        text, secs = _time_compiled(fn, args, reps)
-        summ = analyze_hlo(text)
-        rows.append({"stage": name, "wall_ms": secs * 1e3,
-                     "dot_flops": summ.dot_flops,
-                     "dot_bytes": summ.dot_bytes})
-    stage_total = sum(r["wall_ms"] for r in rows)
-    step = stages.jitted_day_step(cfg)
-    text = step.lower(params, state, xs).compile().as_text()
-    jax.block_until_ready(step(params, state, xs))
-    best = float("inf")
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        jax.block_until_ready(step(params, state, xs))
-        best = min(best, time.perf_counter() - t0)
-    summ = analyze_hlo(text)
-    rows.append({"stage": "day_step", "wall_ms": best * 1e3,
-                 "dot_flops": summ.dot_flops, "dot_bytes": summ.dot_bytes})
-    for r in rows:
-        r["pct"] = 100.0 * r["wall_ms"] / max(stage_total, 1e-9)
-    return rows
-
-
-def format_stage_table(rows: List[Dict[str, object]]) -> str:
-    """Fixed-width stage-cost table (the CI PR-comment rendering)."""
-    name_w = max([len("stage")] + [len(r["stage"]) for r in rows]) + 2
-    out = ["stage".ljust(name_w) + "   wall_ms      pct     dot_GFLOP"
-           + "    dot_MB"]
-    out.append("-" * (name_w + 44))
-    for r in rows:
-        out.append(r["stage"].ljust(name_w)
-                   + f"{r['wall_ms']:9.2f}  {r['pct']:6.1f}%  "
-                   + f"{r['dot_flops'] / 1e9:12.3f}  "
-                   + f"{r['dot_bytes'] / 1e6:8.2f}")
-    return "\n".join(out)
-
-
 __all__ = [
     "DayTelemetry", "day_telemetry", "mape", "bias", "coverage",
     "level_drift", "telemetry_records", "write_jsonl", "read_jsonl",
-    "profile_stages", "format_stage_table", "TRACE_FIELDS",
+    "TRACE_FIELDS",
 ]

@@ -34,7 +34,6 @@ from repro.sim import (SimConfig, build_batch, build_params,
                        scenario_rows, telemetry_records, telemetry_rows,
                        write_jsonl, read_jsonl, DayTelemetry,
                        TELEMETRY_COLUMNS, TRACE_FIELDS, format_table)
-from repro.sim import telemetry as T
 from repro.sim.engine import _day_xs
 from repro.sim.ledger import DayMetrics
 
@@ -216,25 +215,6 @@ def test_trace_records_roundtrip_jsonl(tmp_path):
     # wrong batch geometry is rejected loudly
     with pytest.raises(ValueError):
         telemetry_records(traj["telemetry"], [scens[0].name], 2)
-
-
-def test_profile_stages_rows(tmp_path):
-    """The stage profiler attributes cost across the real stage list and
-    its table renders (host-side satellite of the tentpole)."""
-    cfg = SimConfig(**CFG_KW)
-    sc = default_library(DAYS)[0]
-    p = build_params(cfg, sc, 0, DAYS)
-    s = jax.jit(make_init(cfg))(p)
-    rows = T.profile_stages(cfg.stage_config(), p, s, reps=1)
-    assert [r["stage"] for r in rows] == [
-        "power_fit", "forecast", "carbon", "optimize", "observe",
-        "day_step"]
-    for r in rows:
-        assert r["wall_ms"] > 0.0 and r["pct"] >= 0.0
-    stage_pct = sum(r["pct"] for r in rows if r["stage"] != "day_step")
-    assert abs(stage_pct - 100.0) < 1e-6
-    table = T.format_stage_table(rows)
-    assert "optimize" in table and "wall_ms" in table
 
 
 # ------------------------------------------------------- report std fixes

@@ -111,3 +111,33 @@ def test_sharded_rollout_compiles(v5e, monkeypatch, flags):
     hlo = jax.jit(rollout_batch_sharded(cfg, days, mesh)).lower(
         sds).compile().as_text()
     assert "tpu_custom_call" in hlo
+
+
+def test_rollout_kernel_keeps_its_name_under_the_epoch_scope(
+        one_chip, monkeypatch):
+    """The one-chip rollout with the kernel on: the named scopes leave
+    the Pallas kernel's name as it was (the chip benchmark checks it),
+    and every kernel call of the compiled program sits under
+    ``solver.pgd_epoch``, the scope a trace's kernel events are read
+    by."""
+    import re
+
+    from repro.kernels.vcc_pgd import ops
+    from repro.sim import (SimConfig, build_batch, default_library,
+                           rollout_batch)
+    monkeypatch.setattr(ops, "tpu_available", lambda: True)
+    days = 2
+    cfg = SimConfig(n_clusters=8, n_campuses=2, n_zones=2, hist_days=14)
+    batch = build_batch(cfg, default_library(days)[:2], [0], days)
+    sds = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        batch)
+    lowered = jax.jit(rollout_batch(cfg, days)).lower(sds)
+    assert set(re.findall(r'kernel_name = "(\w+)"', lowered.as_text())) \
+        == {"_pgd_kernel"}
+    calls = [ln for ln in lowered.compile().as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert calls
+    for ln in calls:
+        path = re.search(r'op_name="([^"]*)"', ln).group(1)
+        assert "/solver.pgd_epoch/" in path, path
