@@ -1,15 +1,21 @@
 """Pallas TPU kernel: fused VCC projected-gradient epoch.
 
-Tiling: grid = (n_clusters / TC,); each step loads a (TC, 24) cluster tile
-(delta, eta, pi, pow_nom, lo, ub + per-cluster scalars) into VMEM and runs
-the FULL inner optimization epoch — ``iters`` x [gradient of the linearized
-carbon+peak objective → 50-step bisection projection onto the conservation
-simplex slab] — without touching HBM between iterations. The day-ahead
-optimizer calls this once per dual-ascent round for the whole fleet
-(~O(100k) clusters x 24 h), so HBM round-trips per PGD iteration are the
-hotspot being removed.
+Layout: clusters on lanes, hours on sublanes. The wrappers take and return
+the ``(n, H)`` arrays of ``ref`` and move clusters onto the last axis
+outside the ``pallas_call`` (plain XLA transposes), padding it to the
+lane tile. Grid = (n_padded / tile,); each step loads an ``(H, tile)``
+block of every wide operand (delta, eta, pi, pow_nom, lo, ub) and a
+``(1, tile)`` block of every per-cluster scalar into VMEM and runs the
+FULL inner optimization epoch — ``iters`` x [gradient of the linearized
+carbon+peak objective → 50-step bisection projection onto the
+conservation simplex slab] — without touching HBM between iterations.
+Every reduction over hours (softmax max and sum, the bisection's bracket
+and clipped sum) runs down the sublanes of each vreg, elementwise across
+its 128 clusters, so the epoch fills every lane and needs no cross-lane
+traffic. The day-ahead optimizer calls this once per dual-ascent round
+for the whole fleet.
 
-``temp`` and ``lambda_e`` ride in as broadcast (n, 1) operands rather than
+``temp`` and ``lambda_e`` ride in as broadcast (1, n) operands rather than
 compile-time constants: the day cycle derives ``temp`` from the problem
 inside jit, so they may be traced scalars.
 
@@ -23,28 +29,32 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+LANES = 128
 DEFAULT_TILE = 256
+ENS_TILE = 128    # smaller cluster tile: each block also carries K members
 
 
 def _project_rows(z, lo, ub, proj_iters):
-    """Shared in-VMEM bisection projection onto {sum_h = 0} ∩ [lo, ub]
-    (same math as ref.project_row; rows independent). The ONE copy both
-    kernels call — the identical-members bitwise contract between the
-    plain and ensemble epochs rides on them projecting identically."""
-    a = jnp.min(z, 1) - jnp.max(ub, 1)
-    b = jnp.max(z, 1) - jnp.min(lo, 1)
+    """Shared in-VMEM bisection projection of each cluster's hours onto
+    {sum_h = 0} ∩ [lo, ub] (same math as ref.project_row). z/lo/ub:
+    (H, tile), clusters on lanes and independent; every reduction runs
+    over axis 0, the hours. The ONE copy all kernels call — the
+    identical-members bitwise contract between the plain and ensemble
+    epochs rides on them projecting identically."""
+    a = jnp.min(z, 0, keepdims=True) - jnp.max(ub, 0, keepdims=True)
+    b = jnp.max(z, 0, keepdims=True) - jnp.min(lo, 0, keepdims=True)
 
     def pbody(i, ab):
         a, b = ab
         m = 0.5 * (a + b)
-        f = jnp.sum(jnp.clip(z - m[:, None], lo, ub), axis=1)
+        f = jnp.sum(jnp.clip(z - m, lo, ub), axis=0, keepdims=True)
         a = jnp.where(f > 0, m, a)
         b = jnp.where(f > 0, b, m)
         return a, b
 
     a, b = jax.lax.fori_loop(0, proj_iters, pbody, (a, b))
     nu = 0.5 * (a + b)
-    return jnp.clip(z - nu[:, None], lo, ub)
+    return jnp.clip(z - nu, lo, ub)
 
 
 def _carry(x):
@@ -56,27 +66,44 @@ def _carry(x):
     return x + 0.0
 
 
+def _lane_tile(n, tile):
+    """A block's lane extent: the whole ``n`` below one vreg's lanes,
+    else ``tile`` (a multiple of 128)."""
+    return n if n < LANES else tile
+
+
+def _to_lanes(x, n, pad, fill=0.0):
+    """Clusters onto the lane axis: (..., n, H) -> (..., H, n + pad) and
+    (n, 1) or a scalar -> (1, n + pad); dead lanes hold ``fill``."""
+    x = jnp.asarray(x)
+    if x.ndim == 0:
+        x = jnp.broadcast_to(x.astype(jnp.float32), (n, 1))
+    x = jnp.swapaxes(x, -1, -2)
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)],
+                   constant_values=fill)
+
+
 def _pgd_kernel(delta_ref, eta_ref, pi_ref, pow_ref, tau_ref, price_ref,
                 lo_ref, ub_ref, lr_ref, temp_ref, lame_ref, out_ref, *,
                 iters, proj_iters):
-    delta = delta_ref[...].astype(jnp.float32)
+    delta = delta_ref[...].astype(jnp.float32)        # (H, TC)
     eta = eta_ref[...].astype(jnp.float32)
     pi = pi_ref[...].astype(jnp.float32)
     pow_nom = pow_ref[...].astype(jnp.float32)
-    tau24 = tau_ref[...].astype(jnp.float32)
+    tau24 = tau_ref[...].astype(jnp.float32)          # (1, TC)
     price = price_ref[...].astype(jnp.float32)
     lo = lo_ref[...].astype(jnp.float32)
     ub = ub_ref[...].astype(jnp.float32)
     lr = lr_ref[...].astype(jnp.float32)
-    temp = temp_ref[...].astype(jnp.float32)          # (TC, 1) broadcast
-    lambda_e = lame_ref[...].astype(jnp.float32)      # (TC, 1) broadcast
+    temp = temp_ref[...].astype(jnp.float32)          # (1, TC) broadcast
+    lambda_e = lame_ref[...].astype(jnp.float32)      # (1, TC) broadcast
 
     def body(i, d):
         pow_h = pow_nom + pi * d * tau24
         s = pow_h / temp
-        s = s - jnp.max(s, axis=1, keepdims=True)
+        s = s - jnp.max(s, axis=0, keepdims=True)
         e = jnp.exp(s)
-        w = e / jnp.sum(e, axis=1, keepdims=True)
+        w = e / jnp.sum(e, axis=0, keepdims=True)
         grad = (lambda_e * eta + price * w) * pi * tau24
         return _project_rows(d - lr * grad, lo, ub, proj_iters)
 
@@ -87,41 +114,41 @@ def _pgd_kernel(delta_ref, eta_ref, pi_ref, pow_ref, tau_ref, price_ref,
 def _pgd_ens_kernel(delta_ref, eta_ref, pi_ref, pow_ref, tau_ref, price_ref,
                     lo_ref, ub_ref, lr_ref, temp_ref, lame_ref, risk_ref,
                     out_ref, *, iters, proj_iters):
-    """CVaR ensemble epoch: blocks carry a (K, TC, H) member tile of
+    """CVaR ensemble epoch: blocks carry a (K, H, TC) member tile of
     eta/pow_nom; the member axis is reduced IN-KERNEL (per-cluster
     soft-CVaR tilt, anchored on member 0 — mirrors ref.pgd_step_ens_arrays
     op for op, so identical members collapse bitwise). Every member-axis
-    value keeps its trailing unit hour axis, (K, TC, 1): Mosaic lowers no
-    (TC, 1) -> (TC,) relayout of a kernel value."""
-    delta = delta_ref[...].astype(jnp.float32)          # (TC, H)
-    eta_e = eta_ref[...].astype(jnp.float32)            # (K, TC, H)
+    value keeps its unit hour axis, (K, 1, TC), so it stays on the
+    clusters' lanes."""
+    delta = delta_ref[...].astype(jnp.float32)          # (H, TC)
+    eta_e = eta_ref[...].astype(jnp.float32)            # (K, H, TC)
     pi = pi_ref[...].astype(jnp.float32)
-    pow_e = pow_ref[...].astype(jnp.float32)            # (K, TC, H)
-    tau24 = tau_ref[...].astype(jnp.float32)            # (TC, 1)
+    pow_e = pow_ref[...].astype(jnp.float32)            # (K, H, TC)
+    tau24 = tau_ref[...].astype(jnp.float32)            # (1, TC)
     price = price_ref[...].astype(jnp.float32)
     lo = lo_ref[...].astype(jnp.float32)
     ub = ub_ref[...].astype(jnp.float32)
     lr = lr_ref[...].astype(jnp.float32)
-    temp = temp_ref[...].astype(jnp.float32)            # (TC, 1) broadcast
-    lambda_e = lame_ref[...].astype(jnp.float32)        # (TC, 1) broadcast
-    risk_s = risk_ref[...].astype(jnp.float32)          # (TC, 1) broadcast
+    temp = temp_ref[...].astype(jnp.float32)            # (1, TC) broadcast
+    lambda_e = lame_ref[...].astype(jnp.float32)        # (1, TC) broadcast
+    risk_s = risk_ref[...].astype(jnp.float32)          # (1, TC) broadcast
 
     def body(i, d):
-        ph = pow_e + (pi * d * tau24)[None]             # (K, TC, H)
+        ph = pow_e + (pi * d * tau24)[None]             # (K, H, TC)
         s = ph / temp[None]
-        s = s - jnp.max(s, axis=-1, keepdims=True)
+        s = s - jnp.max(s, axis=1, keepdims=True)
         e = jnp.exp(s)
-        w_peak = e / jnp.sum(e, axis=-1, keepdims=True)
-        cost = lambda_e[None] * jnp.sum(eta_e * ph, axis=-1, keepdims=True) \
-            + price[None] * jnp.sum(w_peak * ph, axis=-1,
-                                    keepdims=True)      # (K, TC, 1)
+        w_peak = e / jnp.sum(e, axis=1, keepdims=True)
+        cost = lambda_e[None] * jnp.sum(eta_e * ph, axis=1, keepdims=True) \
+            + price[None] * jnp.sum(w_peak * ph, axis=1,
+                                    keepdims=True)      # (K, 1, TC)
         z = cost - cost[:1]
         dev = cost - jnp.mean(cost, axis=0, keepdims=True)
         scale = jnp.mean(jnp.abs(dev), axis=0, keepdims=True) + 1e-9
         t = risk_s[None] * z / scale
         t = t - jnp.max(t, axis=0, keepdims=True)
         et = jnp.exp(t)
-        wm = et / jnp.sum(et, axis=0, keepdims=True)     # (K, TC, 1)
+        wm = et / jnp.sum(et, axis=0, keepdims=True)     # (K, 1, TC)
         eta_w = eta_e[0] + jnp.sum(wm * (eta_e - eta_e[:1]), axis=0)
         w_w = w_peak[0] + jnp.sum(wm * (w_peak - w_peak[:1]), axis=0)
         grad = (lambda_e * eta_w + price * w_w) * pi * tau24
@@ -143,35 +170,28 @@ def pgd_epoch_pallas(delta, eta, pi, pow_nom, tau24, price, lo, ub, lr, *,
                      temp, lambda_e, iters: int, proj_iters: int = 50,
                      tile: int = DEFAULT_TILE, interpret: bool = False):
     """All matrices (n, H); tau24/price/lr (n, 1); temp/lambda_e scalar
-    (float or traced). Returns new delta."""
+    (float or traced). Returns new delta (n, H)."""
     n, H = delta.shape
-    tile = min(tile, n)
+    tile = _lane_tile(n, tile)
     pad = (-n) % tile
-
-    def p2(x):
-        return jnp.pad(x, ((0, pad), (0, 0)))
-
-    temp_a = jnp.broadcast_to(jnp.asarray(temp, jnp.float32), (n, 1))
-    lame_a = jnp.broadcast_to(jnp.asarray(lambda_e, jnp.float32), (n, 1))
-    # pad temp with ones: the body divides by it in dead padded rows
-    temp_a = jnp.pad(temp_a, ((0, pad), (0, 0)), constant_values=1.0)
-    args = [p2(x) for x in (delta, eta, pi, pow_nom, tau24, price, lo, ub,
-                            lr)] + [temp_a, p2(lame_a)]
-    nt = (n + pad) // tile
+    args = [_to_lanes(x, n, pad) for x in (delta, eta, pi, pow_nom, tau24,
+                                           price, lo, ub, lr)]
+    args += [_to_lanes(temp, n, pad, fill=1.0),   # dead lanes divide by it
+             _to_lanes(lambda_e, n, pad)]
     kernel = functools.partial(_pgd_kernel, iters=iters,
                                proj_iters=proj_iters)
-    wide = pl.BlockSpec((tile, H), lambda i: (i, 0))
-    slim = pl.BlockSpec((tile, 1), lambda i: (i, 0))
+    wide = pl.BlockSpec((H, tile), lambda i: (0, i))
+    slim = pl.BlockSpec((1, tile), lambda i: (0, i))
     out = pl.pallas_call(
         kernel,
-        grid=(nt,),
+        grid=((n + pad) // tile,),
         in_specs=[wide, wide, wide, wide, slim, slim, wide, wide, slim,
                   slim, slim],
         out_specs=wide,
-        out_shape=_out_shape((n + pad, H), delta.dtype, args),
+        out_shape=_out_shape((H, n + pad), delta.dtype, args),
         interpret=interpret,
     )(*args)
-    return out[:n]
+    return out[:, :n].T
 
 
 def _joint_kernel(d_ref, s_ref, eta_ref, pi_ref, pow_ref, tau_ref, uif_ref,
@@ -179,46 +199,46 @@ def _joint_kernel(d_ref, s_ref, eta_ref, pi_ref, pow_ref, tau_ref, uif_ref,
                   temp_ref, lame_ref, dout_ref, gs_ref, *, drop_limit,
                   proj_iters):
     """Fused joint spatio-temporal step (mirrors ref.joint_step_arrays op
-    for op): recompute the temporal bounds from the shifted budget
-    tau + s, take the linearized carbon + softmax-peak gradient at the
-    shifted point, project delta exactly, and emit the per-cluster shift
-    gradient. The fleet-coupled s projection (sum_c s = 0) happens
-    outside the cluster-tiled grid."""
-    d = d_ref[...].astype(jnp.float32)               # (TC, H)
-    s = s_ref[...].astype(jnp.float32)               # (TC, 1)
+    for op, clusters on lanes): recompute the temporal bounds from the
+    shifted budget tau + s, take the linearized carbon + softmax-peak
+    gradient at the shifted point, project delta exactly, and emit the
+    per-cluster shift gradient. The fleet-coupled s projection
+    (sum_c s = 0) happens outside the cluster-tiled grid."""
+    d = d_ref[...].astype(jnp.float32)               # (H, TC)
+    s = s_ref[...].astype(jnp.float32)               # (1, TC)
     eta = eta_ref[...].astype(jnp.float32)
     pi = pi_ref[...].astype(jnp.float32)
     pow_nom = pow_ref[...].astype(jnp.float32)
-    tau = tau_ref[...].astype(jnp.float32)           # (TC, 1)
+    tau = tau_ref[...].astype(jnp.float32)           # (1, TC)
     u_if = uif_ref[...].astype(jnp.float32)
     u_if_q = uifq_ref[...].astype(jnp.float32)
     ratio = ratio_ref[...].astype(jnp.float32)
-    u_pow_cap = upow_ref[...].astype(jnp.float32)    # (TC, 1)
-    capacity = cap_ref[...].astype(jnp.float32)      # (TC, 1)
-    price = price_ref[...].astype(jnp.float32)       # (TC, 1)
-    lr_d = lr_ref[...].astype(jnp.float32)           # (TC, 1)
-    temp = temp_ref[...].astype(jnp.float32)         # (TC, 1) broadcast
-    lambda_e = lame_ref[...].astype(jnp.float32)     # (TC, 1) broadcast
+    u_pow_cap = upow_ref[...].astype(jnp.float32)    # (1, TC)
+    capacity = cap_ref[...].astype(jnp.float32)      # (1, TC)
+    price = price_ref[...].astype(jnp.float32)       # (1, TC)
+    lr_d = lr_ref[...].astype(jnp.float32)           # (1, TC)
+    temp = temp_ref[...].astype(jnp.float32)         # (1, TC) broadcast
+    lambda_e = lame_ref[...].astype(jnp.float32)     # (1, TC) broadcast
 
     tau_s = tau + s
     t24 = jnp.clip(tau_s / 24.0, 1e-9, None)
     ub = jnp.minimum((u_pow_cap - u_if_q) / t24 - 1.0,
                      (capacity / ratio - u_if) / t24 - 1.0)
     ub = jnp.clip(ub, -drop_limit, 24.0)
-    feas = (jnp.sum(ub, axis=1, keepdims=True) >= 0.0) \
+    feas = (jnp.sum(ub, axis=0, keepdims=True) >= 0.0) \
         & (tau_s > 1e-6) \
-        & jnp.all(ub > -drop_limit + 1e-9, axis=1, keepdims=True)
+        & jnp.all(ub > -drop_limit + 1e-9, axis=0, keepdims=True)
     lo = jnp.where(feas, jnp.full_like(ub, -drop_limit), 0.0)
     ub = jnp.where(feas, ub, 0.0)
 
     pow_h = pow_nom + pi * (d * tau_s + s) / 24.0
     z = pow_h / temp
-    z = z - jnp.max(z, axis=1, keepdims=True)
+    z = z - jnp.max(z, axis=0, keepdims=True)
     e = jnp.exp(z)
-    w = e / jnp.sum(e, axis=1, keepdims=True)
+    w = e / jnp.sum(e, axis=0, keepdims=True)
     gcoef = (lambda_e * eta + price * w) * pi
     g_d = gcoef * (tau_s / 24.0)
-    g_s = jnp.sum(gcoef * (1.0 + d), axis=1, keepdims=True) / 24.0
+    g_s = jnp.sum(gcoef * (1.0 + d), axis=0, keepdims=True) / 24.0
     d2 = _project_rows(d - lr_d * g_d, lo, ub, proj_iters)
     dout_ref[...] = d2.astype(dout_ref.dtype)
     gs_ref[...] = g_s.astype(gs_ref.dtype)
@@ -229,43 +249,33 @@ def joint_step_pallas(delta, s, eta, pi, pow_nom, tau, u_if, u_if_q, ratio,
                       drop_limit: float, proj_iters: int = 50,
                       tile: int = DEFAULT_TILE, interpret: bool = False):
     """Wide operands (n, H); slim operands (n, 1); temp/lambda_e scalar
-    (float or traced); drop_limit static. Returns (delta', g_s (n, 1))."""
+    (float or traced); drop_limit static. Returns (delta' (n, H),
+    g_s (n, 1))."""
     n, H = delta.shape
-    tile = min(tile, n)
+    tile = _lane_tile(n, tile)
     pad = (-n) % tile
-
-    def p2(x, fill=0.0):
-        return jnp.pad(x, ((0, pad), (0, 0)), constant_values=fill)
-
-    def scal(v, fill=0.0):
-        a = jnp.broadcast_to(jnp.asarray(v, jnp.float32), (n, 1))
-        return jnp.pad(a, ((0, pad), (0, 0)), constant_values=fill)
-
-    args = [p2(delta), p2(s), p2(eta), p2(pi), p2(pow_nom), p2(tau),
-            p2(u_if), p2(u_if_q),
-            p2(ratio, fill=1.0),       # dead rows divide by ratio
-            p2(u_pow_cap), p2(capacity), p2(price), p2(lr_d),
-            scal(temp, fill=1.0),      # dead rows divide by temp
-            scal(lambda_e)]
-    nt = (n + pad) // tile
+    args = [_to_lanes(x, n, pad) for x in (delta, s, eta, pi, pow_nom, tau,
+                                           u_if, u_if_q)]
+    args += [_to_lanes(ratio, n, pad, fill=1.0)]  # dead lanes divide by it
+    args += [_to_lanes(x, n, pad) for x in (u_pow_cap, capacity, price,
+                                            lr_d)]
+    args += [_to_lanes(temp, n, pad, fill=1.0),   # dead lanes divide by it
+             _to_lanes(lambda_e, n, pad)]
     kernel = functools.partial(_joint_kernel, drop_limit=drop_limit,
                                proj_iters=proj_iters)
-    wide = pl.BlockSpec((tile, H), lambda i: (i, 0))
-    slim = pl.BlockSpec((tile, 1), lambda i: (i, 0))
+    wide = pl.BlockSpec((H, tile), lambda i: (0, i))
+    slim = pl.BlockSpec((1, tile), lambda i: (0, i))
     d2, g_s = pl.pallas_call(
         kernel,
-        grid=(nt,),
+        grid=((n + pad) // tile,),
         in_specs=[wide, slim, wide, wide, wide, slim, wide, wide, wide,
                   slim, slim, slim, slim, slim, slim],
         out_specs=(wide, slim),
-        out_shape=(_out_shape((n + pad, H), delta.dtype, args),
-                   _out_shape((n + pad, 1), jnp.float32, args)),
+        out_shape=(_out_shape((H, n + pad), delta.dtype, args),
+                   _out_shape((1, n + pad), jnp.float32, args)),
         interpret=interpret,
     )(*args)
-    return d2[:n], g_s[:n]
-
-
-ENS_TILE = 64     # smaller cluster tile: each block also carries K members
+    return d2[:, :n].T, g_s[:, :n].T
 
 
 def pgd_epoch_ens_pallas(delta, eta_e, pi, pow_nom_e, tau24, price, lo, ub,
@@ -276,39 +286,27 @@ def pgd_epoch_ens_pallas(delta, eta_e, pi, pow_nom_e, tau24, price, lo, ub,
     the rest as in ``pgd_epoch_pallas``; ``risk_s`` scalar (float or
     traced) soft-CVaR sharpness (0 = risk-neutral). The grid tiles the
     cluster axis only — every block loads its full K-member slab into VMEM
-    and reduces the member axis in-kernel (K x (tile, H) fits VMEM for the
-    sweep sizes K <= 32, tile = 64)."""
+    and reduces the member axis in-kernel (a (K, H, 128) float32 slab is
+    384 KiB at K = 32, the sweep's largest)."""
     K, n, H = eta_e.shape
-    tile = min(tile, n)
+    tile = _lane_tile(n, tile)
     pad = (-n) % tile
-
-    def p2(x):
-        return jnp.pad(x, ((0, pad), (0, 0)))
-
-    def p3(x):
-        return jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
-
-    def scal(v, fill=0.0):
-        a = jnp.broadcast_to(jnp.asarray(v, jnp.float32), (n, 1))
-        return jnp.pad(a, ((0, pad), (0, 0)), constant_values=fill)
-
-    args = [p2(delta), p3(eta_e), p2(pi), p3(pow_nom_e), p2(tau24),
-            p2(price), p2(lo), p2(ub), p2(lr),
-            scal(temp, fill=1.0),      # body divides by temp in dead rows
-            scal(lambda_e), scal(risk_s)]
-    nt = (n + pad) // tile
+    args = [_to_lanes(x, n, pad) for x in (delta, eta_e, pi, pow_nom_e,
+                                           tau24, price, lo, ub, lr)]
+    args += [_to_lanes(temp, n, pad, fill=1.0),   # dead lanes divide by it
+             _to_lanes(lambda_e, n, pad), _to_lanes(risk_s, n, pad)]
     kernel = functools.partial(_pgd_ens_kernel, iters=iters,
                                proj_iters=proj_iters)
-    wide = pl.BlockSpec((tile, H), lambda i: (i, 0))
-    slim = pl.BlockSpec((tile, 1), lambda i: (i, 0))
-    ens = pl.BlockSpec((K, tile, H), lambda i: (0, i, 0))
+    wide = pl.BlockSpec((H, tile), lambda i: (0, i))
+    slim = pl.BlockSpec((1, tile), lambda i: (0, i))
+    ens = pl.BlockSpec((K, H, tile), lambda i: (0, 0, i))
     out = pl.pallas_call(
         kernel,
-        grid=(nt,),
+        grid=((n + pad) // tile,),
         in_specs=[wide, ens, wide, ens, slim, slim, wide, wide, slim,
                   slim, slim, slim],
         out_specs=wide,
-        out_shape=_out_shape((n + pad, H), delta.dtype, args),
+        out_shape=_out_shape((H, n + pad), delta.dtype, args),
         interpret=interpret,
     )(*args)
-    return out[:n]
+    return out[:, :n].T

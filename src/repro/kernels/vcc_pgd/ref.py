@@ -4,9 +4,12 @@ One epoch = ``iters`` iterations of [linearized-objective gradient →
 exact bisection projection onto {sum_h delta = 0} ∩ [lo, ub]] for a tile of
 clusters. This module is the SINGLE implementation of that math:
 ``core.vcc`` delegates its ``project_conservation`` / ``pgd_step`` to
-``project_row`` / ``pgd_step_arrays``, and the Pallas kernel mirrors the
-same ops in VMEM. ``temp`` / ``lambda_e`` may be Python floats or traced
-scalars (the day-cycle computes ``temp`` from the problem inside jit).
+``project_row`` / ``pgd_step_arrays``, and the Pallas kernels compute the
+same math in VMEM, though not in this layout: they hold clusters on lanes
+and hours on sublanes, so their sums over the 24 hours run in another
+order and agree to rounding. ``temp`` / ``lambda_e`` may be Python floats
+or traced scalars (the day-cycle computes ``temp`` from the problem inside
+jit).
 
 Ensemble (CVaR) variant: ``pgd_step_ens_arrays`` / ``pgd_epoch_ens_ref``
 take K member realizations of (eta, pow_nom) and descend a per-cluster
@@ -66,7 +69,7 @@ def project_row(z, lo, ub, iters: int = 50):
 
 def pgd_step_arrays(d, eta, pi, pow_nom, tau24, price, lo, ub, lr,
                     temp, lambda_e, proj_iters: int = 50):
-    """One projected-gradient step in the kernel's array layout.
+    """One projected-gradient step in the kernel wrappers' array layout.
 
     d/eta/pi/pow_nom/lo/ub: (n, H); tau24/price/lr: (n, 1); temp/lambda_e:
     scalars (possibly traced). The linearized carbon + softmax-peak gradient
@@ -159,7 +162,7 @@ def pgd_epoch_ens_ref(delta, eta_e, pi, pow_nom_e, tau24, price, lo, ub,
 def joint_step_arrays(d, s, eta, pi, pow_nom, tau, u_if, u_if_q, ratio,
                       u_pow_cap, capacity, price, lr_d, temp, lambda_e,
                       drop_limit: float, proj_iters: int = 50):
-    """One fused JOINT spatio-temporal step in the kernel layout.
+    """One fused JOINT spatio-temporal step in the kernel wrappers' layout.
 
     d/eta/pi/pow_nom/u_if/u_if_q/ratio: (n, H); s/tau/u_pow_cap/capacity/
     price/lr_d: (n, 1); temp/lambda_e: scalars (possibly traced);

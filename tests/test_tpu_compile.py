@@ -64,6 +64,31 @@ def test_pgd_epoch_compiles(one_chip):
     assert "tpu_custom_call" in hlo
 
 
+@pytest.mark.parametrize("batch,n", [(44, N), (None, 300)],
+                         ids=["cell-vmap44", "padded-lanes"])
+def test_pgd_epoch_lowers_to_its_one_kernel(one_chip, batch, n):
+    """The plain epoch at the cell's shape, 44 rollouts x (256, 24) under
+    ``jax.vmap``, and at 300 clusters, whose last 128-lane tile holds 84
+    dead lanes. Its lowering calls ``_pgd_kernel`` and no other Pallas
+    kernel: the wrappers' transposes stay XLA ops (the chip benchmark
+    refuses a program with any other kernel)."""
+    import re
+
+    lead = () if batch is None else (batch,)
+    wide, slim = lead + (n, H), lead + (n, 1)
+
+    def epoch(*a):
+        return K.pgd_epoch_pallas(*a, temp=0.5, lambda_e=1.0, iters=80)
+
+    fn = epoch if batch is None else jax.vmap(epoch)
+    args = [jax.ShapeDtypeStruct(s, f32, sharding=one_chip)
+            for s in [wide, wide, wide, wide, slim, slim, wide, wide, slim]]
+    lowered = jax.jit(fn).lower(*args)
+    assert set(re.findall(r'kernel_name = "(\w+)"', lowered.as_text())) \
+        == {"_pgd_kernel"}
+    assert "tpu_custom_call" in lowered.compile().as_text()
+
+
 def test_joint_step_compiles(one_chip):
     wide, slim = (N, H), (N, 1)
     hlo = _compile(lambda *a: K.joint_step_pallas(

@@ -3,6 +3,7 @@ and the Pallas kernel path."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core.vcc import (VCCProblem, delta_bounds,
                             greedy_linear_reference, solve_vcc)
@@ -77,8 +78,13 @@ def test_campus_duals_enforce_contract():
     assert float(sol.mu.max()) > 0.0        # duals actually engaged
 
 
-def test_pallas_epoch_matches_ref():
-    n, H = 12, 24
+@pytest.mark.parametrize("n,tile", [(12, 12), (256, 256), (300, 128)],
+                         ids=["whole-array", "cell-tile", "padded-lanes"])
+def test_pallas_epoch_matches_ref(n, tile):
+    """The clusters-on-lanes kernel (interpret mode) against the oracle: a
+    block narrower than one vreg's lanes, the cell's 256-lane tile, and
+    128-lane tiles with dead lanes padded past the last cluster."""
+    H = 24
     key = jax.random.PRNGKey(5)
     ks = jax.random.split(key, 6)
     delta = jnp.zeros((n, H))
@@ -94,7 +100,7 @@ def test_pallas_epoch_matches_ref():
     d1 = pgd_epoch_ref(delta, eta, pi, pow_nom, tau24, price, lo, ub, lr,
                        **kw)
     d2 = pgd_epoch_pallas(delta, eta, pi, pow_nom, tau24, price, lo, ub, lr,
-                          tile=8, interpret=True, **kw)
+                          tile=tile, interpret=True, **kw)
     assert float(jnp.abs(d1 - d2).max()) < 1e-5
 
 
