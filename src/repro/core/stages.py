@@ -210,6 +210,10 @@ class StepOut(NamedTuple):
     # default None flattens to an EMPTY pytree subtree, so the legacy
     # (telemetry=False) compiled graph stays byte-identical
     telemetry: Optional[object] = None
+    # () cluster-hours whose re-solved suffix the MPC loop accepted this
+    # day, when StageConfig.mpc (whatever the telemetry flag); None, an
+    # empty subtree, in the open loop
+    recourse_hours: Optional[jnp.ndarray] = None
 
 
 @dataclass(frozen=True)
@@ -648,6 +652,7 @@ def make_day_step(cfg: StageConfig):
         arr_hs = xs.get("arrival_hour_scale")
         mdiag = None
         acc = None
+        recourse_hours = None
         with jax.named_scope("stage.observe"):
             if cfg.mpc:
                 (res, cf, u_if, _, vcc_enforced, acc,
@@ -658,6 +663,9 @@ def make_day_step(cfg: StageConfig):
                     eta_act, allowance_frac=cfg.slo_allowance,
                     arr_hour_scale=arr_hs, use_pallas=cfg.use_pallas,
                     interpret=cfg.interpret)
+                # whole hours per cluster (k / 24 * 24 is exact in
+                # float32), so the ordered sum is an exact count
+                recourse_hours = hour_sum(mdiag.recourse_frac * 24.0)
             else:
                 res, cf, u_if, _ = observe_stage(
                     params.truth, state.day, day_key, vcc_curve, cap_day,
@@ -739,7 +747,8 @@ def make_day_step(cfg: StageConfig):
                     trail=trail, recourse=mdiag)
         return new_state, StepOut(res=res, cf=cf, sol=sol,
                                   vcc_curve=vcc_enforced, fc=fc, prob=prob,
-                                  eta_act=eta_act, telemetry=telem)
+                                  eta_act=eta_act, telemetry=telem,
+                                  recourse_hours=recourse_hours)
 
     return step
 

@@ -178,6 +178,10 @@ def make_rollout(cfg: SimConfig, days: int):
                 # (telemetry=False keeps the traj keys — and graph —
                 # exactly the legacy ones)
                 traj["telemetry"] = out.telemetry
+            if cfg.mpc:
+                # (days,) accepted re-plans, beside the telemetry record
+                # and with telemetry off too
+                traj["recourse_hours"] = out.recourse_hours
             return (s, led), traj
 
         xs = jax.tree.map(lambda a: a[:days], _day_xs(params))

@@ -25,8 +25,9 @@ import numpy as np
 
 from repro.core import admission, mpc, stages, vcc
 from repro.core.admission import hour_sum
-from repro.sim import (SimConfig, build_batch, forecast_bust_library,
-                       rollout_batch)
+from repro.sim import (SimConfig, build_batch, build_params,
+                       forecast_bust_library, make_init, rollout_batch)
+from repro.sim.engine import _day_xs
 
 f32 = jnp.float32
 
@@ -190,3 +191,67 @@ def test_mpc_off_rollout_has_zero_recourse_telemetry():
     _, _, traj = rollout_batch(cfg, days)(params)
     assert float(np.abs(np.asarray(
         traj["telemetry"].mpc_recourse_frac)).max()) == 0.0
+
+
+# ------------------------------------------------ the accepted re-plans
+
+LEGACY_TRAJ = {"carbon_kg", "cf_carbon_kg", "kwh", "peak_kw", "queue"}
+
+
+def _bust_batch(cfg, days, n_scen=2):
+    return build_batch(cfg, forecast_bust_library(days=days)[:n_scen],
+                       seeds=[0], days=days)
+
+
+def test_recourse_hours_counts_the_accepted_replans():
+    """mpc=True, rescan: ``traj["recourse_hours"]`` (days,) is the
+    cluster-hours whose re-solved suffix was accepted, the telemetry
+    record's per-cluster recourse fraction times 24 summed over
+    clusters, with telemetry off as with it on."""
+    kw = dict(n_clusters=4, n_campuses=2, n_zones=2, pds_per_cluster=2,
+              hist_days=14, mpc=True)
+    days = 3
+    batch = _bust_batch(SimConfig(**kw), days)
+    _, _, traj = rollout_batch(SimConfig(**kw), days)(batch)
+    _, _, traj_on = rollout_batch(SimConfig(**kw, telemetry=True),
+                                  days)(batch)
+    count = np.asarray(traj["recourse_hours"])
+    frac = np.asarray(traj_on["telemetry"].mpc_recourse_frac)
+    assert count.shape == (2, days)
+    want = (frac * 24).sum(axis=-1)
+    np.testing.assert_array_equal(
+        np.asarray(traj_on["recourse_hours"]), want)
+    np.testing.assert_array_equal(count, want)
+    assert count.max() > 0
+    assert set(traj) == LEGACY_TRAJ | {"recourse_hours"}
+
+
+def test_recourse_hours_zero_with_every_gate_closed():
+    """Every cluster's shaping paused: no re-plan is ever accepted."""
+    cfg = SimConfig(n_clusters=4, n_campuses=2, n_zones=2,
+                    pds_per_cluster=2, hist_days=14, mpc=True)
+    days = 2
+    p = build_params(cfg, forecast_bust_library(days=days)[0], 0, days)
+    s = jax.jit(make_init(cfg))(p)
+    s = s._replace(shaping_allowed=jnp.zeros_like(s.shaping_allowed))
+    step = jax.jit(stages.make_day_step(cfg.stage_config()))
+    _, out = step(p, s, _day_xs(p, 0))
+    assert float(out.recourse_hours) == 0.0
+
+
+def test_mpc_off_traj_and_hlo_unchanged(monkeypatch):
+    """mpc=False: the traj keys are the legacy ones, and the whole
+    rollout lowers to the text of the graph traced with the verbatim
+    pre-MPC admission (``benchmarks/sim_bench.py``'s collapse
+    certificate, at the rollout that now carries the counter)."""
+    from benchmarks import sim_bench
+    cfg = SimConfig(n_clusters=4, n_campuses=2, n_zones=2,
+                    pds_per_cluster=2, hist_days=14)
+    days = 2
+    batch = _bust_batch(cfg, days, n_scen=1)
+    _, _, traj = rollout_batch(cfg, days)(batch)
+    assert set(traj) == LEGACY_TRAJ
+    now = jax.jit(rollout_batch(cfg, days)).lower(batch).as_text()
+    monkeypatch.setattr(admission, "run_day", sim_bench._legacy_run_day)
+    legacy = jax.jit(rollout_batch(cfg, days)).lower(batch).as_text()
+    assert now == legacy

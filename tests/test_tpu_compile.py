@@ -166,3 +166,33 @@ def test_rollout_kernel_keeps_its_name_under_the_epoch_scope(
     for ln in calls:
         path = re.search(r'op_name="([^"]*)"', ln).group(1)
         assert "/solver.pgd_epoch/" in path, path
+
+
+def test_mpc_rollout_calls_only_its_kernel(one_chip, monkeypatch):
+    """The closed-loop rollout as the chip benchmark's MPC cell runs it
+    (MPC on, rescan predictor) with the kernel on: the day-ahead epochs
+    and the hourly suffix epochs both lower to ``_pgd_kernel`` and no
+    other kernel, and each kernel call sits under ``solver.pgd_epoch``,
+    the suffix ones under ``mpc.resolve`` too."""
+    import re
+
+    from repro.kernels.vcc_pgd import ops
+    from repro.sim import (SimConfig, build_batch, forecast_bust_library,
+                           rollout_batch)
+    monkeypatch.setattr(ops, "tpu_available", lambda: True)
+    days = 2
+    cfg = SimConfig(n_clusters=8, n_campuses=2, n_zones=2, hist_days=14,
+                    mpc=True)
+    batch = build_batch(cfg, forecast_bust_library(days)[:2], [0], days)
+    sds = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        batch)
+    lowered = jax.jit(rollout_batch(cfg, days)).lower(sds)
+    assert set(re.findall(r'kernel_name = "(\w+)"', lowered.as_text())) \
+        == {"_pgd_kernel"}
+    paths = [re.search(r'op_name="([^"]*)"', ln).group(1)
+             for ln in lowered.compile().as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert all("/solver.pgd_epoch/" in p for p in paths), paths
+    assert sum("/mpc.resolve/" in p for p in paths) == 1
+    assert sum("/stage.optimize/" in p for p in paths) == 1
