@@ -164,7 +164,8 @@ def solution_diagnostics(p: VCCProblem, delta, mu, *,
       * ``proj_nu_tol`` (n,) — certified tolerance of the conservation
         projection's nu bisection at the solution: the initial bracket
         width (``kernels.vcc_pgd.ref.project_row``'s [a, b]) halved
-        ``proj_iters`` times.
+        ``proj_iters`` times. It certifies the jnp oracle's bisection;
+        the Pallas kernels project exactly.
       * ``dual_resid`` (n_dc,) — relative campus-contract overshoot
         max(0, (sum_c y - L) / L) at the final point (0 = the campus
         dual ascent converged feasibly).

@@ -7,13 +7,13 @@ lane tile. Grid = (n_padded / tile,); each step loads an ``(H, tile)``
 block of every wide operand (delta, eta, pi, pow_nom, lo, ub) and a
 ``(1, tile)`` block of every per-cluster scalar into VMEM and runs the
 FULL inner optimization epoch — ``iters`` x [gradient of the linearized
-carbon+peak objective → 50-step bisection projection onto the
+carbon+peak objective → exact breakpoint projection onto the
 conservation simplex slab] — without touching HBM between iterations.
-Every reduction over hours (softmax max and sum, the bisection's bracket
-and clipped sum) runs down the sublanes of each vreg, elementwise across
-its 128 clusters, so the epoch fills every lane and needs no cross-lane
-traffic. The day-ahead optimizer calls this once per dual-ascent round
-for the whole fleet.
+Every reduction over hours (softmax max and sum, the projection's sum
+and its bracket over the 48 breakpoints) runs down the sublanes of each
+vreg, elementwise across its 128 clusters, so the epoch fills every lane
+and needs no cross-lane traffic. The day-ahead optimizer calls this once
+per dual-ascent round for the whole fleet.
 
 ``temp`` and ``lambda_e`` ride in as broadcast (1, n) operands rather than
 compile-time constants: the day cycle derives ``temp`` from the problem
@@ -34,26 +34,40 @@ DEFAULT_TILE = 256
 ENS_TILE = 128    # smaller cluster tile: each block also carries K members
 
 
-def _project_rows(z, lo, ub, proj_iters):
-    """Shared in-VMEM bisection projection of each cluster's hours onto
-    {sum_h = 0} ∩ [lo, ub] (same math as ref.project_row). z/lo/ub:
-    (H, tile), clusters on lanes and independent; every reduction runs
-    over axis 0, the hours. The ONE copy all kernels call — the
-    identical-members bitwise contract between the plain and ensemble
-    epochs rides on them projecting identically."""
-    a = jnp.min(z, 0, keepdims=True) - jnp.max(ub, 0, keepdims=True)
-    b = jnp.max(z, 0, keepdims=True) - jnp.min(lo, 0, keepdims=True)
+def _project_rows(z, lo, ub):
+    """Exact projection of each cluster's hours onto {sum_h = 0} ∩
+    [lo, ub] (the set of ref.project_row, whose bisection reaches this
+    answer to float resolution). z/lo/ub: (H, tile), clusters on lanes
+    and independent; every reduction runs over axis 0. The ONE copy all
+    kernels call — the identical-members bitwise contract between the
+    plain and ensemble epochs rides on them projecting identically.
 
-    def pbody(i, ab):
-        a, b = ab
-        m = 0.5 * (a + b)
-        f = jnp.sum(jnp.clip(z - m, lo, ub), axis=0, keepdims=True)
-        a = jnp.where(f > 0, m, a)
-        b = jnp.where(f > 0, b, m)
-        return a, b
-
-    a, b = jax.lax.fori_loop(0, proj_iters, pbody, (a, b))
-    nu = 0.5 * (a + b)
+    The answer is clip(z - nu, lo, ub) where f(nu) = sum_h clip(z_h - nu,
+    lo_h, ub_h) crosses 0. f is non-increasing and linear between the 2H
+    breakpoints z - ub, z - lo, so it is evaluated at all of them at once,
+    (2H, tile) on the sublanes, hour by hour and with no sort. (The equal
+    sum of z_h - clip(b, z_h - ub_h, z_h - lo_h) takes one op fewer but
+    cancels, and conserves several times less precisely.) The last
+    breakpoint with f >= 0 and the first with f <= 0 bracket the root,
+    and nu interpolates between them; where f is 0 over an interval,
+    every hour is at a bound there and nu is its end. A row with no
+    breakpoint on one side cannot conserve: nu = -inf puts every hour at
+    ub (sum ub < 0), nu = +inf every hour at lo (sum lo > 0). lo == ub
+    rows come out pinned either way."""
+    b = jnp.concatenate([z - ub, z - lo], axis=0)       # (2H, tile)
+    f = jnp.zeros_like(b)                               # f at each b
+    for h in range(z.shape[0]):
+        f = f + jnp.clip(z[h:h + 1] - b, lo[h:h + 1], ub[h:h + 1])
+    ge, le = f >= 0, f <= 0
+    lo_b = jnp.max(jnp.where(ge, b, -jnp.inf), axis=0, keepdims=True)
+    hi_b = jnp.min(jnp.where(le, b, jnp.inf), axis=0, keepdims=True)
+    f_lo = jnp.min(jnp.where(ge, f, jnp.inf), axis=0, keepdims=True)
+    f_hi = jnp.max(jnp.where(le, f, -jnp.inf), axis=0, keepdims=True)
+    den = f_lo - f_hi
+    t = jnp.where(den > 0, f_lo / jnp.where(den > 0, den, 1.0), 0.0)
+    nu = lo_b + t * (hi_b - lo_b)
+    nu = jnp.where(lo_b == -jnp.inf, -jnp.inf, nu)
+    nu = jnp.where(hi_b == jnp.inf, jnp.inf, nu)
     return jnp.clip(z - nu, lo, ub)
 
 
@@ -85,7 +99,7 @@ def _to_lanes(x, n, pad, fill=0.0):
 
 def _pgd_kernel(delta_ref, eta_ref, pi_ref, pow_ref, tau_ref, price_ref,
                 lo_ref, ub_ref, lr_ref, temp_ref, lame_ref, out_ref, *,
-                iters, proj_iters):
+                iters):
     delta = delta_ref[...].astype(jnp.float32)        # (H, TC)
     eta = eta_ref[...].astype(jnp.float32)
     pi = pi_ref[...].astype(jnp.float32)
@@ -105,7 +119,7 @@ def _pgd_kernel(delta_ref, eta_ref, pi_ref, pow_ref, tau_ref, price_ref,
         e = jnp.exp(s)
         w = e / jnp.sum(e, axis=0, keepdims=True)
         grad = (lambda_e * eta + price * w) * pi * tau24
-        return _project_rows(d - lr * grad, lo, ub, proj_iters)
+        return _project_rows(d - lr * grad, lo, ub)
 
     out_ref[...] = jax.lax.fori_loop(0, iters, body, _carry(delta)).astype(
         out_ref.dtype)
@@ -113,7 +127,7 @@ def _pgd_kernel(delta_ref, eta_ref, pi_ref, pow_ref, tau_ref, price_ref,
 
 def _pgd_ens_kernel(delta_ref, eta_ref, pi_ref, pow_ref, tau_ref, price_ref,
                     lo_ref, ub_ref, lr_ref, temp_ref, lame_ref, risk_ref,
-                    out_ref, *, iters, proj_iters):
+                    out_ref, *, iters):
     """CVaR ensemble epoch: blocks carry a (K, H, TC) member tile of
     eta/pow_nom; the member axis is reduced IN-KERNEL (per-cluster
     soft-CVaR tilt, anchored on member 0 — mirrors ref.pgd_step_ens_arrays
@@ -152,7 +166,7 @@ def _pgd_ens_kernel(delta_ref, eta_ref, pi_ref, pow_ref, tau_ref, price_ref,
         eta_w = eta_e[0] + jnp.sum(wm * (eta_e - eta_e[:1]), axis=0)
         w_w = w_peak[0] + jnp.sum(wm * (w_peak - w_peak[:1]), axis=0)
         grad = (lambda_e * eta_w + price * w_w) * pi * tau24
-        return _project_rows(d - lr * grad, lo, ub, proj_iters)
+        return _project_rows(d - lr * grad, lo, ub)
 
     out_ref[...] = jax.lax.fori_loop(0, iters, body, _carry(delta)).astype(
         out_ref.dtype)
@@ -167,8 +181,8 @@ def _out_shape(shape, dtype, args):
 
 
 def pgd_epoch_pallas(delta, eta, pi, pow_nom, tau24, price, lo, ub, lr, *,
-                     temp, lambda_e, iters: int, proj_iters: int = 50,
-                     tile: int = DEFAULT_TILE, interpret: bool = False):
+                     temp, lambda_e, iters: int, tile: int = DEFAULT_TILE,
+                     interpret: bool = False):
     """All matrices (n, H); tau24/price/lr (n, 1); temp/lambda_e scalar
     (float or traced). Returns new delta (n, H)."""
     n, H = delta.shape
@@ -178,8 +192,7 @@ def pgd_epoch_pallas(delta, eta, pi, pow_nom, tau24, price, lo, ub, lr, *,
                                            price, lo, ub, lr)]
     args += [_to_lanes(temp, n, pad, fill=1.0),   # dead lanes divide by it
              _to_lanes(lambda_e, n, pad)]
-    kernel = functools.partial(_pgd_kernel, iters=iters,
-                               proj_iters=proj_iters)
+    kernel = functools.partial(_pgd_kernel, iters=iters)
     wide = pl.BlockSpec((H, tile), lambda i: (0, i))
     slim = pl.BlockSpec((1, tile), lambda i: (0, i))
     out = pl.pallas_call(
@@ -196,8 +209,7 @@ def pgd_epoch_pallas(delta, eta, pi, pow_nom, tau24, price, lo, ub, lr, *,
 
 def _joint_kernel(d_ref, s_ref, eta_ref, pi_ref, pow_ref, tau_ref, uif_ref,
                   uifq_ref, ratio_ref, upow_ref, cap_ref, price_ref, lr_ref,
-                  temp_ref, lame_ref, dout_ref, gs_ref, *, drop_limit,
-                  proj_iters):
+                  temp_ref, lame_ref, dout_ref, gs_ref, *, drop_limit):
     """Fused joint spatio-temporal step (mirrors ref.joint_step_arrays op
     for op, clusters on lanes): recompute the temporal bounds from the
     shifted budget tau + s, take the linearized carbon + softmax-peak
@@ -228,8 +240,11 @@ def _joint_kernel(d_ref, s_ref, eta_ref, pi_ref, pow_ref, tau_ref, uif_ref,
     feas = (jnp.sum(ub, axis=0, keepdims=True) >= 0.0) \
         & (tau_s > 1e-6) \
         & jnp.all(ub > -drop_limit + 1e-9, axis=0, keepdims=True)
-    lo = jnp.where(feas, jnp.full_like(ub, -drop_limit), 0.0)
     ub = jnp.where(feas, ub, 0.0)
+    # -drop_limit (0 where infeasible) and never above ub; taking it as a
+    # minimum with ub lays it out row by row, as _project_rows slices it
+    # (a broadcast of a (1, TC) row has no rows to slice)
+    lo = jnp.minimum(jnp.where(feas, -drop_limit, 0.0), ub)
 
     pow_h = pow_nom + pi * (d * tau_s + s) / 24.0
     z = pow_h / temp
@@ -239,15 +254,15 @@ def _joint_kernel(d_ref, s_ref, eta_ref, pi_ref, pow_ref, tau_ref, uif_ref,
     gcoef = (lambda_e * eta + price * w) * pi
     g_d = gcoef * (tau_s / 24.0)
     g_s = jnp.sum(gcoef * (1.0 + d), axis=0, keepdims=True) / 24.0
-    d2 = _project_rows(d - lr_d * g_d, lo, ub, proj_iters)
+    d2 = _project_rows(d - lr_d * g_d, lo, ub)
     dout_ref[...] = d2.astype(dout_ref.dtype)
     gs_ref[...] = g_s.astype(gs_ref.dtype)
 
 
 def joint_step_pallas(delta, s, eta, pi, pow_nom, tau, u_if, u_if_q, ratio,
                       u_pow_cap, capacity, price, lr_d, *, temp, lambda_e,
-                      drop_limit: float, proj_iters: int = 50,
-                      tile: int = DEFAULT_TILE, interpret: bool = False):
+                      drop_limit: float, tile: int = DEFAULT_TILE,
+                      interpret: bool = False):
     """Wide operands (n, H); slim operands (n, 1); temp/lambda_e scalar
     (float or traced); drop_limit static. Returns (delta' (n, H),
     g_s (n, 1))."""
@@ -261,8 +276,7 @@ def joint_step_pallas(delta, s, eta, pi, pow_nom, tau, u_if, u_if_q, ratio,
                                             lr_d)]
     args += [_to_lanes(temp, n, pad, fill=1.0),   # dead lanes divide by it
              _to_lanes(lambda_e, n, pad)]
-    kernel = functools.partial(_joint_kernel, drop_limit=drop_limit,
-                               proj_iters=proj_iters)
+    kernel = functools.partial(_joint_kernel, drop_limit=drop_limit)
     wide = pl.BlockSpec((H, tile), lambda i: (0, i))
     slim = pl.BlockSpec((1, tile), lambda i: (0, i))
     d2, g_s = pl.pallas_call(
@@ -280,8 +294,7 @@ def joint_step_pallas(delta, s, eta, pi, pow_nom, tau, u_if, u_if_q, ratio,
 
 def pgd_epoch_ens_pallas(delta, eta_e, pi, pow_nom_e, tau24, price, lo, ub,
                          lr, *, temp, lambda_e, risk_s, iters: int,
-                         proj_iters: int = 50, tile: int = ENS_TILE,
-                         interpret: bool = False):
+                         tile: int = ENS_TILE, interpret: bool = False):
     """CVaR ensemble epoch. eta_e/pow_nom_e: (K, n, H) member stacks;
     the rest as in ``pgd_epoch_pallas``; ``risk_s`` scalar (float or
     traced) soft-CVaR sharpness (0 = risk-neutral). The grid tiles the
@@ -295,8 +308,7 @@ def pgd_epoch_ens_pallas(delta, eta_e, pi, pow_nom_e, tau24, price, lo, ub,
                                            tau24, price, lo, ub, lr)]
     args += [_to_lanes(temp, n, pad, fill=1.0),   # dead lanes divide by it
              _to_lanes(lambda_e, n, pad), _to_lanes(risk_s, n, pad)]
-    kernel = functools.partial(_pgd_ens_kernel, iters=iters,
-                               proj_iters=proj_iters)
+    kernel = functools.partial(_pgd_ens_kernel, iters=iters)
     wide = pl.BlockSpec((H, tile), lambda i: (0, i))
     slim = pl.BlockSpec((1, tile), lambda i: (0, i))
     ens = pl.BlockSpec((K, H, tile), lambda i: (0, 0, i))

@@ -7,7 +7,8 @@ clusters. This module is the SINGLE implementation of that math:
 ``project_row`` / ``pgd_step_arrays``, and the Pallas kernels compute the
 same math in VMEM, though not in this layout: they hold clusters on lanes
 and hours on sublanes, so their sums over the 24 hours run in another
-order and agree to rounding. ``temp`` / ``lambda_e`` may be Python floats
+order, and they project exactly at the breakpoints where this bisects,
+so the two agree to rounding. ``temp`` / ``lambda_e`` may be Python floats
 or traced scalars (the day-cycle computes ``temp`` from the problem inside
 jit).
 

@@ -5,9 +5,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from jax.experimental import pallas as pl
+
 from repro.core.vcc import (VCCProblem, delta_bounds,
-                            greedy_linear_reference, solve_vcc)
-from repro.kernels.vcc_pgd.kernel import pgd_epoch_pallas
+                            greedy_linear_reference, solve_vcc,
+                            suffix_bounds)
+from repro.kernels.vcc_pgd.kernel import _project_rows, pgd_epoch_pallas
 from repro.kernels.vcc_pgd.ref import pgd_epoch_ref
 
 
@@ -78,12 +81,17 @@ def test_campus_duals_enforce_contract():
     assert float(sol.mu.max()) > 0.0        # duals actually engaged
 
 
-@pytest.mark.parametrize("n,tile", [(12, 12), (256, 256), (300, 128)],
-                         ids=["whole-array", "cell-tile", "padded-lanes"])
-def test_pallas_epoch_matches_ref(n, tile):
+@pytest.mark.parametrize("n,tile,hour", [(12, 12, None), (256, 256, None),
+                                         (300, 128, None), (256, 256, 12)],
+                         ids=["whole-array", "cell-tile", "padded-lanes",
+                              "suffix-hour12"])
+def test_pallas_epoch_matches_ref(n, tile, hour):
     """The clusters-on-lanes kernel (interpret mode) against the oracle: a
-    block narrower than one vreg's lanes, the cell's 256-lane tile, and
-    128-lane tiles with dead lanes padded past the last cluster."""
+    block narrower than one vreg's lanes, the cell's 256-lane tile,
+    128-lane tiles with dead lanes padded past the last cluster, and the
+    hourly re-solve's suffix polytope at hour 12 (``vcc.suffix_bounds``:
+    elapsed hours pinned at the realized deviations, clusters that can no
+    longer conserve pinned everywhere)."""
     H = 24
     key = jax.random.PRNGKey(5)
     ks = jax.random.split(key, 6)
@@ -96,12 +104,100 @@ def test_pallas_epoch_matches_ref(n, tile):
     lo = jnp.full((n, H), -0.8)
     ub = 0.5 + jax.random.uniform(ks[4], (n, H))
     lr = 0.01 * jnp.ones((n, 1))
+    if hour is not None:
+        p = make_problem(n=n, seed=7)
+        lo0, ub0, _ = delta_bounds(p)
+        delta = lo0 + jax.random.uniform(ks[5], (n, H)) * (ub0 - lo0)
+        lo, ub, feasible = suffix_bounds(p, delta, hour)
+        assert 0 < int(feasible.sum()) < n        # both kinds of cluster
     kw = dict(temp=10.0, lambda_e=0.3, iters=30)
     d1 = pgd_epoch_ref(delta, eta, pi, pow_nom, tau24, price, lo, ub, lr,
                        **kw)
     d2 = pgd_epoch_pallas(delta, eta, pi, pow_nom, tau24, price, lo, ub, lr,
                           tile=tile, interpret=True, **kw)
     assert float(jnp.abs(d1 - d2).max()) < 1e-5
+
+
+def _exact_projection(z, lo, ub):
+    """Sort-based exact projection of each column of (H, n) ``z`` onto
+    {sum = 0} n [lo, ub], in float64: f(nu) = sum clip(z - nu, lo, ub)
+    is linear between neighbouring sorted breakpoints z - ub, z - lo.
+    Where no breakpoint has f <= 0 (sum lo > 0) every hour goes to lo;
+    where none has f >= 0 (sum ub < 0), to ub."""
+    z, lo, ub = (np.asarray(a, np.float64).T for a in (z, lo, ub))
+    out = np.empty_like(z)
+    for c in range(z.shape[0]):
+        bp = np.sort(np.concatenate([z[c] - ub[c], z[c] - lo[c]]))
+        f = np.array([np.clip(z[c] - b, lo[c], ub[c]).sum() for b in bp])
+        if f[-1] > 0:
+            nu = np.inf
+        elif f[0] < 0:
+            nu = -np.inf
+        else:
+            j = max(int(np.argmax(f <= 0)), 1)
+            b0, b1, f0, f1 = bp[j - 1], bp[j], f[j - 1], f[j]
+            nu = b0 + (f0 * (b1 - b0) / (f0 - f1) if f0 > f1 else 0.0)
+        out[c] = np.clip(z[c] - nu, lo[c], ub[c])
+    return out.T
+
+
+def _projection_case(case, n, rng):
+    """(z, lo, ub), each (24, n) float32, clusters on the last axis."""
+    H = 24
+    z = 2.0 * rng.standard_normal((H, n))
+    lo = -rng.uniform(0.2, 1.0, (H, n))
+    ub = rng.uniform(0.2, 1.5, (H, n))
+    if case == "duplicate-breakpoints":
+        z = rng.choice([-0.5, 0.0, 0.5], (H, n))
+        lo, ub = np.full((H, n), -0.5), np.full((H, n), 0.5)
+    elif case == "flat-zero":        # half the hours at ub, half at lo
+        w = rng.uniform(0.2, 1.0, (1, n))
+        lo, ub = np.full((H, n), -w), np.full((H, n), w)
+        z = np.where(np.arange(H)[:, None] % 2 == 0, 1.0, -1.0) \
+            * (w + rng.uniform(1.0, 3.0, (H, n)))
+    elif case == "zero-box":
+        lo, ub = np.zeros((H, n)), np.zeros((H, n))
+    elif case == "pinned-hours":     # elapsed hours at the committed value
+        for h in (1, 12, 23):
+            lo[h] = ub[h] = rng.uniform(-0.2, 0.2, n)
+    elif case == "sum-ub-negative":
+        ub = -rng.uniform(0.01, 0.3, (H, n))
+        lo = ub - rng.uniform(0.0, 1.0, (H, n))
+    elif case == "sum-lo-positive":
+        lo = rng.uniform(0.01, 0.3, (H, n))
+        ub = lo + rng.uniform(0.0, 1.0, (H, n))
+    return tuple(np.asarray(a, np.float32) for a in (z, lo, ub))
+
+
+@pytest.mark.parametrize("n", [8, 256])
+@pytest.mark.parametrize("case", ["random", "duplicate-breakpoints",
+                                  "flat-zero", "zero-box", "pinned-hours",
+                                  "sum-ub-negative", "sum-lo-positive"])
+def test_kernel_projection_is_exact(case, n):
+    """The kernels' shared projection (interpret mode, (24, n) blocks with
+    clusters on lanes) against a sort-based exact oracle: within 1e-6 of
+    each column's scale, conserving to 1e-5 of it, inside the box
+    exactly. Rows that cannot conserve go wholly to the bound they
+    violate, exactly, as the jnp oracle's bisection sends them."""
+    z, lo, ub = _projection_case(case, n,
+                                 np.random.default_rng(len(case) + n))
+
+    def kernel(z_ref, lo_ref, ub_ref, out_ref):
+        out_ref[...] = _project_rows(z_ref[...], lo_ref[...], ub_ref[...])
+
+    x = np.asarray(pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct(z.shape, jnp.float32),
+        interpret=True)(z, lo, ub))
+    scale = np.abs(z).max(axis=0)
+    assert np.all(np.abs(x - _exact_projection(z, lo, ub)).max(axis=0)
+                  <= 1e-6 * scale)
+    assert np.all((lo <= x) & (x <= ub))
+    if case == "sum-ub-negative":
+        np.testing.assert_array_equal(x, ub)
+    elif case == "sum-lo-positive":
+        np.testing.assert_array_equal(x, lo)
+    else:
+        assert np.all(np.abs(x.sum(axis=0)) <= 1e-5 * scale)
 
 
 def test_infeasible_clusters_get_capacity_vcc():
