@@ -12,25 +12,29 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
+import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
-from repro.core import fleet as F  # noqa: E402
+from repro.sim import (Scenario, SimConfig, build_params,  # noqa: E402
+                       make_day_step, make_init)
+from repro.sim.engine import _day_xs  # noqa: E402
 
 
 def main():
     print("== CICS quickstart: init fleet (incl. 91-day telemetry burn-in)")
-    cfg = F.FleetConfig(n_clusters=8, n_campuses=2, n_zones=2, lambda_e=0.6,
-                        seed=0)
-    st = F.init_fleet(cfg)
-    rec = {}
-    st = F.day_cycle(st, rec)
-    sol, res, eta = rec["sol"], rec["result"], rec["intensity"]
+    cfg = SimConfig(n_clusters=8, n_campuses=2, n_zones=2,
+                    pds_per_cluster=4, hist_days=91)
+    sc = Scenario("quickstart", lambda_e=0.6, lambda_p=0.05, gamma=0.05)
+    params = build_params(cfg, sc, seed=0, days=1)
+    st = jax.jit(make_init(cfg))(params)
+    st, out = jax.jit(make_day_step(cfg))(params, st, _day_xs(params, 0))
+    sol, res, eta = out.sol, out.res, out.eta_act
     shaped = np.asarray(sol.shaped & st.shaping_allowed)
     print(f"shaped clusters: {shaped.sum()}/{cfg.n_clusters}")
     c = int(np.nonzero(shaped)[0][0])
     print(f"\ncluster {c} — hourly view (paper Fig 3):")
     print(f"{'h':>3} {'carbon':>7} {'VCC':>7} {'flex':>6} {'inflex':>7}")
-    vcc = np.asarray(rec['vcc'][c])
+    vcc = np.asarray(out.vcc_curve[c])
     flex = np.asarray(res.usage_flex[c])
     uif = np.asarray(res.usage_total[c] - res.usage_flex[c])
     for h in range(24):

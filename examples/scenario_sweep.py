@@ -42,8 +42,7 @@ tolerance, not bitwise).
 DayTelemetry record stacked into the rollout (`SimConfig(telemetry=
 True)`) and prints a second table of solver convergence and forecast
 calibration per scenario (see README "Observability"); ``--trace PATH``
-additionally exports the raw per scenario x seed x day records as JSONL
-— the same artifact CI uploads from the bench smoke job.
+additionally exports the raw per scenario x seed x day records as JSONL.
 """
 import argparse
 import time

@@ -9,11 +9,11 @@ pre-shift + joint spatio-temporal optimization). Every optimizer is an
 assembly over the ONE generic projected-gradient layer (solver.py:
 projections, smooth peak, lr scaling, dual ascent, kernel-epoch
 dispatch). ``stages.py`` composes the pipelines into THE staged day cycle
-(pure stage functions -> one pure day step) shared by both drivers;
-``fleet.py`` is the legacy mutable-FleetState adapter over it.
+(pure stage functions -> one pure day step), which ``sim.engine`` scans
+over days and vmaps over a (scenario x seed) batch.
 """
-from repro.core import (admission, carbon, fleet, forecast, power, risk,
-                        slo, solver, spatial, stages, vcc)
+from repro.core import (admission, carbon, forecast, power, risk, slo,
+                        solver, spatial, stages, vcc)
 
-__all__ = ["admission", "carbon", "fleet", "forecast", "power", "risk",
-           "slo", "solver", "spatial", "stages", "vcc"]
+__all__ = ["admission", "carbon", "forecast", "power", "risk", "slo",
+           "solver", "spatial", "stages", "vcc"]

@@ -14,8 +14,8 @@ tunable (the paper selects them by out-of-sample MAPE exploration —
 the 97%-ile capacity requirement Theta (eq. 2) and the (1-gamma) inflexible
 quantile for power capping; eq. (3) yields the alpha inflation factor.
 
-Everything is vmap-friendly: functions take one cluster's history; fleet.py
-vmaps them across clusters.
+Everything is vmap-friendly: functions take one cluster's history;
+``stages.forecast_stage`` vmaps them across clusters.
 """
 from __future__ import annotations
 

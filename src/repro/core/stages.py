@@ -24,10 +24,10 @@ consumers change, or the sim engine's bitwise batched==sequential parity
 contract breaks. ``make_day_step`` composes the stages into one pure day;
 ``burnin_step``/``make_init`` build a burned-in state under ``lax.scan``.
 
-Both drivers are thin adapters over this module: ``sim.engine`` scans/vmaps
-``make_day_step`` across days and a (scenario x seed) batch, and the legacy
-``core.fleet`` API steps the SAME jitted day (``jitted_day_step``) from a
-mutable ``FleetState``. There is no second copy of the day cycle.
+``sim.engine`` scans/vmaps ``make_day_step`` across days and a
+(scenario x seed) batch; ``jitted_day_step`` is the same day compiled
+alone, for drivers that step it from a Python loop. There is no second
+copy of the day cycle.
 """
 from __future__ import annotations
 
@@ -107,8 +107,8 @@ def true_ratio(truth, usage):
 
 def synth_params(seed: int, n_clusters: int, pds_per_cluster: int,
                  n_zones: int) -> Dict[str, object]:
-    """Synthesize the array-only fleet parameter leaves shared by BOTH
-    entry points (sim scenarios and the legacy FleetConfig): latent truth,
+    """Synthesize the array-only fleet parameter leaves that
+    ``sim.scenarios.build_params`` composes scenarios onto: latent truth,
     PD power-curve truth, PD usage fractions, stacked zone params, and the
     rollout PRNG key. Pure: identical inputs -> identical arrays."""
     key = jax.random.PRNGKey(seed)
@@ -195,8 +195,7 @@ class SimState(NamedTuple):
 class StepOut(NamedTuple):
     """Everything one day produces beyond the carried state. Consumers
     keep what they need (the engine reduces to DayMetrics inside its scan
-    body; the legacy ``day_cycle`` records sol/vcc/result) — unused leaves
-    are dead-code-eliminated by XLA."""
+    body) — unused leaves are dead-code-eliminated by XLA."""
     res: admission.DayResult          # shaped admission result
     cf: admission.DayResult           # unshaped counterfactual result
     sol: vcc.VCCSolution
@@ -575,7 +574,7 @@ def make_day_step(cfg: StageConfig):
 
     Returns step(params, state, xs) -> (state', StepOut) where xs holds
     this day's scenario-schedule slices (all-ones = the paper's nominal
-    operation, which is what the legacy fleet path uses)."""
+    operation)."""
     slo_cfg = slo.SLOConfig(margin=cfg.slo_margin,
                             pause_days=cfg.slo_pause_days)
     if cfg.streaming and cfg.n_members > 1:
@@ -755,20 +754,10 @@ def make_day_step(cfg: StageConfig):
 
 @functools.lru_cache(maxsize=None)
 def jitted_day_step(cfg: StageConfig):
-    """The SAME jitted executable for every standalone driver of the day
-    cycle (legacy fleet.day_cycle, sequential debugging, parity tests) —
-    one compile per StageConfig, bitwise-identical results across callers."""
+    """The day step compiled alone, one compile per StageConfig, for
+    drivers that step it from a Python loop
+    (``sim.engine.rollout_sequential``)."""
     return jax.jit(make_day_step(cfg))
-
-
-def ones_xs(n_clusters: int, n_campuses: int, n_zones: int
-            ) -> Dict[str, jnp.ndarray]:
-    """Neutral (nominal-operation) scenario slices for one day."""
-    return {"green_scale": jnp.ones((n_zones,), f32),
-            "coal_scale": jnp.ones((n_zones,), f32),
-            "cap_scale": jnp.ones((n_clusters,), f32),
-            "arrival_scale": jnp.ones((n_clusters,), f32),
-            "campus_scale": jnp.ones((n_campuses,), f32)}
 
 
 # ------------------------------------------------------------ init/burn-in

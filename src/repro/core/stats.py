@@ -43,7 +43,7 @@ to float tolerance (moment-form vs centered-form least squares). From
 there the two paths are different estimators of the same quantities —
 the rescan re-partitions a sliding H-window into weeks each day, which
 has no O(1) update — and a >=14-day dual run pins their drift to a
-documented tolerance (also CI-gated in benchmarks/sim_bench.py).
+documented tolerance.
 """
 from __future__ import annotations
 
@@ -207,7 +207,7 @@ def predictor_nbytes(pred: PredictorState) -> int:
 
 def replaced_hist_nbytes(state) -> int:
     """Bytes of the seven rescan history arrays PredictorState replaces
-    (``hist_*`` in a rescan SimState/FleetState)."""
+    (``hist_*`` in a rescan SimState)."""
     return int(sum(getattr(state, k).size * getattr(state, k).dtype.itemsize
                    for k in ("hist_uif", "hist_flex_daily", "hist_res_daily",
                              "hist_usage", "hist_res", "hist_tr_pred",

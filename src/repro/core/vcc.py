@@ -321,8 +321,7 @@ def solve_vcc_suffix(p: VCCProblem, delta0, mu0, hour, *,
     ``solver.pgd_epochs`` inside ``solver.dual_ascent`` with the
     projection acting on the masked suffix polytope (``suffix_bounds``)
     — but the default schedule is outer 2 x inner 8 = 16 PGD steps vs
-    the full solve's 20 x 80 = 1600: the < 1/24-of-a-day-solve recourse
-    budget the ROADMAP gate demands (benchmarks/sim_bench.py)."""
+    the full solve's 20 x 80 = 1600."""
     n, H = p.eta.shape
     lo, ub, feasible = suffix_bounds(p, delta0, hour)
     temp = solver.peak_temperature(p.pow_nom, temp_frac)
@@ -360,12 +359,11 @@ def solve_vcc_batched(p: VCCProblem, **kw) -> VCCSolution:
 
 def synthetic_problem(n: int = 12, seed: int = 7, n_campuses: int = 2
                       ) -> VCCProblem:
-    """The canonical synthetic fleetwide problem shared by the parity
-    tests (tests/test_stages_parity.py, tests/test_risk.py) and the
-    solve-cost benchmark probe (benchmarks/sim_bench.py): a diurnal
-    intensity curve + noisy inflexible load with uncontended campus
-    limits and drop_limit=1.0. ONE recipe so the benchmarked problem can
-    never drift from the tested one."""
+    """The canonical synthetic fleetwide problem shared by the solver
+    tests (tests/test_stages_parity.py, tests/test_risk.py,
+    tests/test_mpc.py, ...) and ``chip_smoke.py``: a diurnal intensity
+    curve + noisy inflexible load with uncontended campus limits and
+    drop_limit=1.0."""
     key = jax.random.PRNGKey(seed)
     ks = jax.random.split(key, 4)
     H = 24
@@ -390,11 +388,7 @@ def synthetic_zonal_problem(n: int = 12, seed: int = 3,
     """``synthetic_problem`` with a strong spatial carbon gradient
     (alternating dirty/clean clusters) and tightened machine capacity, so
     temporal shaping saturates in the dirty clusters and exporting budget
-    is what a spatial/joint optimizer can exploit. The ONE zonal recipe
-    shared by the joint tests (tests/test_joint.py) and the
-    joint-vs-sequential benchmark probe (benchmarks/sim_bench.py) — same
-    convention as ``synthetic_problem``: the benchmarked problem can
-    never drift from the tested one."""
+    is what a spatial/joint optimizer can exploit (tests/test_joint.py)."""
     p = synthetic_problem(n, seed=seed, n_campuses=n_campuses)
     scale = jnp.where(jnp.arange(n) % 2 == 0, 2.2, 0.5)[:, None]
     return dataclasses.replace(p, eta=p.eta * scale,

@@ -1,7 +1,6 @@
 """Persistent XLA compilation cache for the entry-point scripts.
 
-Entry points (``chip_smoke.py``, ``benchmarks/run.py``,
-``benchmarks/sim_bench.py``, ``examples/scenario_sweep.py``) call
+Entry points (``chip_smoke.py``, ``examples/scenario_sweep.py``) call
 ``enable_compile_cache()`` once at start-up. The library never sets a
 cache on import, and neither do the tests.
 """

@@ -1,6 +1,6 @@
 """Reduce batched rollouts into per-scenario summary tables.
 
-Consumed by benchmarks (BENCH_sim.json rows) and examples/scenario_sweep.py.
+Consumed by examples/scenario_sweep.py and the tests.
 Input: a batched Ledger whose leading axis is scenario-major x seed-minor
 (the layout produced by scenarios.build_batch).
 """
@@ -139,9 +139,9 @@ def mpc_recourse_rows(led_mpc: Ledger, led_open: Ledger,
     against the open-loop rollouts of the SAME (scenario x seed) batch.
     ``carbon_vs_open_pct > 0`` means hourly recourse emitted less carbon
     than committing to the 00:00 plan; ``flex24h_vs_open_pp`` is the
-    within-24h flex service improvement in percentage points. The MPC
-    acceptance gate (benchmarks/sim_bench.py) requires every
-    forecast-busting row to improve on at least one of the two."""
+    within-24h flex service improvement in percentage points.
+    ``test_mpc_recourse_beats_open_loop`` requires every forecast-busting
+    row to improve on at least one of the two."""
     rows = scenario_rows(led_mpc, scenario_names, n_seeds)
     open_rows = scenario_rows(led_open, scenario_names, n_seeds)
     for r, q in zip(rows, open_rows):

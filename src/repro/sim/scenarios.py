@@ -324,8 +324,8 @@ def mobility_sweep_library(days: int = 14,
     so its realized rollouts match the sequential path only to float
     tolerance). Run with ``SimConfig(joint_spatial=True)`` and compare
     against the same batch under ``joint_spatial=False`` for the
-    joint-vs-sequential carbon delta (``report.mobility_sweep_rows``,
-    ``benchmarks/sim_bench.py``).
+    joint-vs-sequential carbon delta (``report.mobility_sweep_rows``;
+    tests/test_joint.py holds every row to >= -0.5%).
     """
     return [
         Scenario(f"mobility{int(round(100 * m)):03d}",
@@ -345,8 +345,8 @@ def forecast_bust_library(days: int = 6) -> List[Scenario]:
     forecasts, then the ACTUAL intensity / arrivals are hit by
     randomly-placed intra-day blocks the planner never saw. These are the
     rows where the closed loop must beat (or match) the open loop on
-    carbon or unmet-flex — ``report.mpc_recourse_rows`` /
-    ``benchmarks/sim_bench.py`` gate on every row."""
+    carbon or within-24h flex service — ``report.mpc_recourse_rows``
+    rows, each checked by ``test_mpc_recourse_beats_open_loop``."""
     return [
         Scenario("intraday_carbon_spike",
                  "unforecasted x1.8 intensity block, 8h/day, random hours",

@@ -28,7 +28,6 @@ from repro.sim import MOBILITY_SWEEP
 f32 = jnp.float32
 
 
-# the ONE zonal recipe, shared with the sim_bench joint probe
 _zonal_problem = vcc.synthetic_zonal_problem
 
 
@@ -207,6 +206,30 @@ def test_joint_rollout_through_engine():
     np.testing.assert_allclose(np.asarray(led[True].carbon_kg[0]),
                                np.asarray(led[False].carbon_kg[0]),
                                rtol=1e-3)
+
+
+def test_joint_rollouts_within_half_percent_of_sequential_carbon():
+    """Rollout-level tripwire on the mobility sweep: joint rollouts emit
+    no more than 0.5% more carbon than the sequential pre-shift
+    rollouts of the same batch. Plan-level dominance is exact
+    (``test_joint_dominates_sequential_on_mobility_sweep``); realized
+    carbon after sampled load and admission can move either way, so
+    this only catches joint plans that systematically realize worse."""
+    from repro.sim import (SimConfig, build_batch, mobility_sweep_library,
+                           mobility_sweep_rows, rollout_batch)
+    days, seeds = 3, [0]
+    scens = mobility_sweep_library(days, mobilities=(0.0, 0.3))
+    led = {}
+    for joint in (True, False):
+        cfg = SimConfig(n_clusters=4, n_campuses=2, n_zones=2,
+                        pds_per_cluster=2, hist_days=14,
+                        joint_spatial=joint)
+        batch = build_batch(cfg, scens, seeds, days)
+        _, led[joint], _ = rollout_batch(cfg, days)(batch)
+    rows = mobility_sweep_rows(led[True], led[False],
+                               [s.name for s in scens], len(seeds))
+    for r in rows:
+        assert r["carbon_vs_sequential_pct"] >= -0.5, r
 
 
 def test_joint_with_ensemble_stage():

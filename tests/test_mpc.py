@@ -3,10 +3,9 @@
 Contract under test:
 
   * ``StageConfig.mpc`` / ``SimConfig.mpc`` default OFF — the closed
-    loop is opt-in; the mpc=False day step never imports the recourse
-    path (the byte-identical-HLO collapse certificate itself lives in
-    benchmarks/sim_bench.py where the verbatim pre-MPC ``run_day`` is
-    monkeypatched in).
+    loop is opt-in; the mpc=False rollout lowers to the same HLO text
+    as the graph traced with the verbatim pre-MPC ``run_day``
+    (``_legacy_run_day`` below, monkeypatched in).
   * ``vcc.solve_vcc_suffix`` pins elapsed hours at the committed
     deviations, keeps the suffix inside the day-ahead box and preserves
     whole-day conservation; infeasible clusters keep their plan.
@@ -18,15 +17,19 @@ Contract under test:
     00:00 plan.
   * An mpc=True rollout runs under jit+vmap end to end (with streaming
     and telemetry stacked on) and emits sane recourse diagnostics.
+  * On every forecast-bust scenario the closed loop improves carbon or
+    within-24h flex service over the open loop of the same batch.
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import admission, mpc, stages, vcc
 from repro.core.admission import hour_sum
 from repro.sim import (SimConfig, build_batch, build_params,
-                       forecast_bust_library, make_init, rollout_batch)
+                       forecast_bust_library, make_init,
+                       mpc_recourse_rows, rollout_batch)
 from repro.sim.engine import _day_xs
 
 f32 = jnp.float32
@@ -239,12 +242,45 @@ def test_recourse_hours_zero_with_every_gate_closed():
     assert float(out.recourse_hours) == 0.0
 
 
+def _legacy_run_day(vcc, u_if, arrivals, ratio, capacity, queue0, power_fn,
+                    intensity, allowance_frac: float = 0.25):
+    """Verbatim pre-MPC ``admission.run_day`` (inline tick + hard-coded
+    0.25 late-day allowance; ``allowance_frac`` accepted for call
+    compatibility, unused — the default-config trace passes 0.25). The
+    collapse probe traces the day step against THIS to certify that
+    ``mpc=False`` still compiles to the byte-identical open-loop HLO."""
+    def tick(queue, inp):
+        vcc_h, uif_h, arr_h, r_h = inp
+        flex_room_res = jnp.clip(vcc_h - uif_h * r_h, 0.0, None)
+        flex_room = flex_room_res / jnp.clip(r_h, 1.0, None)
+        flex_room = jnp.minimum(flex_room,
+                                jnp.clip(capacity - uif_h, 0.0, None))
+        demand = queue + arr_h
+        use_flex = jnp.minimum(demand, flex_room)
+        queue = demand - use_flex
+        return queue, (use_flex, queue)
+
+    xs = (vcc.T, u_if.T, arrivals.T, ratio.T)
+    queue_end, (use_flex, queue_traj) = jax.lax.scan(tick, queue0, xs)
+    use_flex = use_flex.T                       # (n, 24)
+    usage_total = u_if + use_flex
+    reservations = usage_total * ratio
+    power = jax.vmap(power_fn, in_axes=1, out_axes=1)(usage_total)
+    carbon = power * intensity
+    arrived = hour_sum(arrivals)
+    served = hour_sum(use_flex)
+    allowance = 0.25 * arrived
+    unmet = jnp.clip(queue_end - queue0 - allowance, 0.0, None)
+    return admission.DayResult(
+        usage_flex=use_flex, usage_total=usage_total,
+        reservations=reservations, power=power, carbon=carbon,
+        served=served, arrived=arrived, queue_end=queue_end, unmet=unmet)
+
+
 def test_mpc_off_traj_and_hlo_unchanged(monkeypatch):
     """mpc=False: the traj keys are the legacy ones, and the whole
     rollout lowers to the text of the graph traced with the verbatim
-    pre-MPC admission (``benchmarks/sim_bench.py``'s collapse
-    certificate, at the rollout that now carries the counter)."""
-    from benchmarks import sim_bench
+    pre-MPC admission (``_legacy_run_day``)."""
     cfg = SimConfig(n_clusters=4, n_campuses=2, n_zones=2,
                     pds_per_cluster=2, hist_days=14)
     days = 2
@@ -252,6 +288,38 @@ def test_mpc_off_traj_and_hlo_unchanged(monkeypatch):
     _, _, traj = rollout_batch(cfg, days)(batch)
     assert set(traj) == LEGACY_TRAJ
     now = jax.jit(rollout_batch(cfg, days)).lower(batch).as_text()
-    monkeypatch.setattr(admission, "run_day", sim_bench._legacy_run_day)
+    monkeypatch.setattr(admission, "run_day", _legacy_run_day)
     legacy = jax.jit(rollout_batch(cfg, days)).lower(batch).as_text()
     assert now == legacy
+
+
+# ------------------------------------------- closed loop against open loop
+
+BUST_DAYS = 4
+BUST_SCENARIOS = forecast_bust_library(BUST_DAYS)
+
+
+@pytest.fixture(scope="module")
+def recourse_rows():
+    """One forecast-bust batch (3 scenarios x seeds 0, 1, 4 days) rolled
+    out twice, mpc=True against mpc=False."""
+    base = dict(n_clusters=4, n_campuses=2, n_zones=2, pds_per_cluster=2,
+                hist_days=14)
+    cfg_open = SimConfig(**base)
+    seeds = [0, 1]
+    batch = build_batch(cfg_open, BUST_SCENARIOS, seeds, BUST_DAYS)
+    _, led_open, _ = rollout_batch(cfg_open, BUST_DAYS)(batch)
+    _, led_mpc, _ = rollout_batch(SimConfig(**base, mpc=True),
+                                  BUST_DAYS)(batch)
+    rows = mpc_recourse_rows(led_mpc, led_open,
+                             [s.name for s in BUST_SCENARIOS], len(seeds))
+    return {r["scenario"]: r for r in rows}
+
+
+@pytest.mark.parametrize("scenario", [s.name for s in BUST_SCENARIOS])
+def test_mpc_recourse_beats_open_loop(recourse_rows, scenario):
+    """Hourly recourse improves carbon (%) or within-24h flex service
+    (pp) over committing to the 00:00 plan, on every bust row."""
+    r = recourse_rows[scenario]
+    best = max(r["carbon_vs_open_pct"], r["flex24h_vs_open_pp"])
+    assert best >= 0.0, (r["carbon_vs_open_pct"], r["flex24h_vs_open_pp"])

@@ -1,101 +1,11 @@
-"""The staged core is the ONE day cycle: the legacy fleet adapters and the
-sim engine must produce identical states/VCCs from the same inputs, and
-solve_vcc's kernel dispatch path must match its jnp oracle path on CPU."""
+"""solve_vcc's kernel dispatch path must match its jnp oracle path on CPU,
+and the dispatcher must take traced scalars under jit and vmap."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
-from repro.core import fleet as F
 from repro.core import vcc
-from repro.sim import (Scenario, SimConfig, build_params, make_day_step,
-                       make_init)
-from repro.sim.engine import _day_xs
 
-N, M, Z, PDS, HIST = 4, 2, 2, 2, 14
-SEED = 0
-LAMBDA_E, LAMBDA_P, GAMMA = 0.5, 0.05, 0.05
-
-SIM_CFG = SimConfig(n_clusters=N, n_campuses=M, n_zones=Z,
-                    pds_per_cluster=PDS, hist_days=HIST)
-FLEET_CFG = F.FleetConfig(n_clusters=N, n_campuses=M, n_zones=Z,
-                          pds_per_cluster=PDS, lambda_e=LAMBDA_E,
-                          lambda_p=LAMBDA_P, gamma=GAMMA, seed=SEED,
-                          hist_days=HIST)
-
-
-@pytest.fixture(scope="module")
-def engine_side():
-    sc = Scenario("parity_probe", lambda_e=LAMBDA_E, lambda_p=LAMBDA_P,
-                  gamma=GAMMA)
-    params = build_params(SIM_CFG, sc, seed=SEED, days=3)
-    state = jax.jit(make_init(SIM_CFG))(params)
-    return params, state
-
-
-@pytest.fixture(scope="module")
-def fleet_side():
-    return F.init_fleet(FLEET_CFG)
-
-
-def test_legacy_burnin_matches_engine_init(engine_side, fleet_side):
-    """init_fleet (FleetState wrapper) and the engine's make_init burn in
-    the SAME state bitwise — one lax.scan burn-in, two adapters."""
-    _, s = engine_side
-    st = fleet_side
-    for name, a, b in (
-            ("hist_uif", st.hist_uif, s.hist_uif),
-            ("hist_usage", st.hist_usage, s.hist_usage),
-            ("hist_res", st.hist_res, s.hist_res),
-            ("hist_flex_daily", st.hist_flex_daily, s.hist_flex_daily),
-            ("hist_res_daily", st.hist_res_daily, s.hist_res_daily),
-            ("hist_tr_pred", st.hist_tr_pred, s.hist_tr_pred),
-            ("hist_uif_pred", st.hist_uif_pred, s.hist_uif_pred),
-            ("carbon_hist", st.carbon_hist, s.carbon_hist),
-            ("campus_limit", st.campus_limit, s.campus_limit),
-            ("queue", st.queue, s.queue),
-            ("cf_queue", st.cf_queue, s.cf_queue)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
-                                      err_msg=name)
-    assert int(st.day) == int(s.day) == HIST
-
-
-def test_day_cycle_matches_engine_day_step(engine_side, fleet_side):
-    """Three legacy day_cycle days == three engine day_step days, bitwise:
-    identical VCC curves, admission results, rolled histories, SLO state."""
-    params, s = engine_side
-    st = fleet_side
-    step = jax.jit(make_day_step(SIM_CFG))
-    for d in range(3):
-        s, out = step(params, s, _day_xs(params, d))
-        rec = {}
-        st = F.day_cycle(st, rec)
-        np.testing.assert_array_equal(np.asarray(rec["vcc"]),
-                                      np.asarray(out.vcc_curve),
-                                      err_msg=f"vcc day {d}")
-        for name, a, b in (
-                ("delta", rec["sol"].delta, out.sol.delta),
-                ("shaped", rec["sol"].shaped, out.sol.shaped),
-                ("carbon", rec["result"].carbon, out.res.carbon),
-                ("served", rec["result"].served, out.res.served),
-                ("cf_carbon", rec["cf_result"].carbon, out.cf.carbon),
-                ("intensity", rec["intensity"], out.eta_act)):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
-                                          err_msg=f"{name} day {d}")
-        # carried state stays in lockstep
-        for name, a, b in (
-                ("queue", st.queue, s.queue),
-                ("cf_queue", st.cf_queue, s.cf_queue),
-                ("hist_usage", st.hist_usage, s.hist_usage),
-                ("shaping_allowed", st.shaping_allowed,
-                 s.shaping_allowed),
-                ("pause_left", st.slo_state["pause_left"], s.pause_left)):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
-                                          err_msg=f"{name} day {d}")
-        assert int(st.day) == int(s.day)
-
-
-# the shared synthetic recipe (identical arrays to the old inline copy)
 _vcc_problem = vcc.synthetic_problem
 
 

@@ -14,13 +14,12 @@ Contracts pinned here:
     predictor (same actuals) keeps every forecast within a documented
     tolerance: the two paths are different-memory estimators of the same
     quantities (the rescan re-partitions a sliding window daily, which
-    has no O(1) update). Also CI-gated in benchmarks/sim_bench.py.
+    has no O(1) update).
   * **State size** — the streaming carry replaces the seven (n, H[, 24])
-    history windows with O(1)-in-H state (strictly smaller already at
-    modest H; the H=364 gate lives in the bench).
-  * **Plumbing** — streaming rollouts run under jit+vmap end to end; the
-    legacy fleet adapters drive the same streaming day step; ensembles
-    (n_members > 1) are rejected with a clear error.
+    history windows with O(1)-in-H state: strictly smaller already at
+    modest H, and at the year-scale H=364.
+  * **Plumbing** — streaming rollouts run under jit+vmap end to end;
+    ensembles (n_members > 1) are rejected with a clear error.
 """
 import dataclasses
 
@@ -28,7 +27,6 @@ import jax
 import numpy as np
 import pytest
 
-from repro.core import fleet as F
 from repro.core import stages, stats
 from repro.sim import (Scenario, SimConfig, build_batch, build_params,
                        make_day_step, make_init, rollout_batch)
@@ -132,6 +130,20 @@ def test_streaming_state_strictly_smaller(rescan_side, predictor):
     assert state_nbytes(s_stream) < state_nbytes(s)
 
 
+def test_streaming_state_smaller_than_hist_windows_at_h364():
+    """At a year of history (H=364) the PredictorState is strictly
+    smaller than the seven hist_* windows it replaces, read from the
+    burned-in states' shapes (no burn-in runs)."""
+    params = build_params(CFG, SCEN, seed=0, days=1)
+    rescan = jax.eval_shape(
+        make_init(dataclasses.replace(CFG, hist_days=364)), params)
+    stream = jax.eval_shape(
+        make_init(dataclasses.replace(CFG_S, hist_days=364)), params)
+    pred_b = stats.predictor_nbytes(stream.pred)
+    hist_b = stats.replaced_hist_nbytes(rescan)
+    assert pred_b < hist_b, (pred_b, hist_b)
+
+
 def test_streaming_rollout_batch_runs_under_jit_vmap():
     days = 5
     scens = [SCEN, Scenario("stream_probe_hot", lambda_e=2.0)]
@@ -147,38 +159,6 @@ def test_streaming_rollout_batch_runs_under_jit_vmap():
     assert state.hist_uif.shape[2] == 0
     assert state.carbon_hist.shape[2] == stats.WEEK
     assert state.pred.usage_ring.shape[-2:] == (stats.USAGE_WINDOW, 24)
-
-
-def test_fleet_streaming_day_cycle_matches_engine():
-    """The legacy FleetState adapters thread the streaming carry through
-    the SAME jitted staged step: two days of fleet.day_cycle equal two
-    engine day steps bitwise."""
-    fcfg = F.FleetConfig(n_clusters=N, n_campuses=M, n_zones=Z,
-                         pds_per_cluster=PDS, lambda_e=0.5, lambda_p=0.05,
-                         gamma=0.05, seed=0, hist_days=HIST, streaming=True)
-    sc = Scenario("stream_parity", lambda_e=0.5, lambda_p=0.05, gamma=0.05)
-    params = build_params(CFG_S, sc, seed=0, days=3)
-    s = jax.jit(make_init(CFG_S))(params)
-    st = F.init_fleet(fcfg)
-    assert st.pred is not None
-    np.testing.assert_array_equal(np.asarray(st.pred.uif_wmean),
-                                  np.asarray(s.pred.uif_wmean))
-    step = jax.jit(make_day_step(CFG_S))
-    for d in range(2):
-        s, out = step(params, s, _day_xs(params, d))
-        rec = {}
-        st = F.day_cycle(st, rec)
-        np.testing.assert_array_equal(np.asarray(rec["vcc"]),
-                                      np.asarray(out.vcc_curve),
-                                      err_msg=f"vcc day {d}")
-        np.testing.assert_array_equal(np.asarray(st.queue),
-                                      np.asarray(s.queue),
-                                      err_msg=f"queue day {d}")
-        np.testing.assert_array_equal(
-            np.asarray(st.pred.theta_err_ring),
-            np.asarray(s.pred.theta_err_ring),
-            err_msg=f"theta ring day {d}")
-    assert int(st.day) == int(s.day)
 
 
 def test_streaming_rejects_forecast_ensembles():
