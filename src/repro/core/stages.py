@@ -307,12 +307,22 @@ def power_stage(hist_usage, lam, capacity, pdt: power.PDTruth, key
     """
     n, npd = lam.shape
     u_cl = hist_usage[:, -28:].reshape(n, -1)                # (n, t)
-    u_pd = (lam[..., None] * u_cl[:, None, :]).reshape(n * npd, -1)
-    u_norm = u_pd / jnp.clip(
-        capacity[:, None, None].repeat(npd, 1).reshape(n * npd, 1),
-        1e-6, None)
+    cap = jnp.clip(capacity, 1e-6, None)[:, None, None]
+
+    def to_pd(v):       # (n, m) cluster usage -> (n, pds, m) PD usage / cap
+        return (lam[..., None] * v[:, None, :]) / cap
+
+    u_norm = to_pd(u_cl).reshape(n * npd, -1)
     p_pd = power.simulate_pd_power(key, pdt, u_norm)
-    coef, breaks = power.fit_pd_models(u_norm, p_pd)
+    # lam >= 0 and cap > 0 make to_pd non-decreasing (in float32 too), so
+    # a PD's order statistics are its cluster's, mapped: one sort per
+    # cluster, not per PD, and the same bits as sorting the PD rows
+    s = jnp.sort(u_cl, axis=-1, stable=False)
+    ends = to_pd(jnp.stack([s[:, 0], s[:, -1]], -1)).reshape(n * npd, 2)
+    breaks = power.quantiles_sorted(s, power.BREAK_QS, to_pd).reshape(
+        n * npd, -1)
+    coef = jax.vmap(power.fit_pd_window)(u_norm, p_pd, ends[:, 0],
+                                         ends[:, 1], breaks)
     # materialization point: keeps the fitted model's numerics independent
     # of how downstream consumers fuse (bitwise batched/sequential parity)
     coef, breaks = jax.lax.optimization_barrier((coef, breaks))

@@ -22,7 +22,7 @@ scenario's non-batched sequential rollout BITWISE, for any batch size,
 and the sharded batch reproduces the unsharded batch bitwise on a
 one-device mesh. This needs batch-invariant numerics — ordered
 reductions for daily totals (`admission.hour_sum`), the pairwise-summed
-normal equations of `power.fit_pd_model`, the elementwise
+normal equations of `power.fit_pd_window`, the elementwise
 `power._solve_spd` / `power.pd_power`, and the `optimization_barrier`
 pins at every stage boundary in `stages` so XLA cannot re-fuse (and
 re-round) a producer when its consumers change.

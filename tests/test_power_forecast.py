@@ -3,8 +3,9 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
-from repro.core import forecast, power
+from repro.core import forecast, power, stages
 
 
 def test_pd_fit_mape_under_5pct():
@@ -20,7 +21,7 @@ def test_pd_fit_mape_under_5pct():
     cpu = 0.2 + 0.6 * jax.random.uniform(jax.random.fold_in(key, 4),
                                          (n_pd, t))
     pw = power.simulate_pd_power(jax.random.fold_in(key, 5), truth, cpu)
-    coef, breaks = power.fit_pd_models(cpu, pw)
+    coef, breaks = jax.jit(jax.vmap(power.fit_pd_model))(cpu, pw)
     mapes = np.asarray(power.daily_mape_b(coef, breaks, cpu, pw))
     # paper: daily MAPE < 5% for > 95% of PDs
     assert (mapes < 0.05).mean() > 0.95, mapes.max()
@@ -36,6 +37,121 @@ def test_slope_is_derivative():
           - power.pd_power(coef, breaks, u - eps)) / (2 * eps)
     sl = power.pd_slope(coef, breaks, u)
     np.testing.assert_allclose(np.asarray(fd), np.asarray(sl), rtol=1e-2)
+
+
+def _ref_basis(u, breaks):
+    """The hinge basis [1, u, relu(u - b_k)...] as a (t, K+2) array."""
+    cols = [jnp.ones_like(u), u]
+    for k in range(breaks.shape[0]):
+        cols.append(jnp.maximum(u - breaks[k], 0.0))
+    return jnp.stack(cols, axis=-1)
+
+
+def _ref_fit(cpu, pw):
+    """The per-PD fit, each row on its own: its own quantiles, min and
+    max, and the full outer products."""
+    qs = jnp.linspace(0.0, 1.0, power.N_BREAKS + 2)[1:-1]
+    breaks = jnp.quantile(cpu, qs)
+    lo = jnp.min(cpu)
+    span = jnp.clip(jnp.max(cpu) - lo, 1e-6, None)
+    X = _ref_basis((cpu - lo) / span, (breaks - lo) / span)
+    XtX = power._pairwise_sum(X[..., :, None] * X[..., None, :], axis=-3) \
+        + 1e-4 * cpu.shape[-1] * jnp.eye(X.shape[-1])
+    Xty = power._pairwise_sum(X * pw[..., None], axis=-2)
+    cx = power._solve_spd(XtX, Xty)
+    coef = jnp.concatenate([cx[:1] - cx[1:2] * lo / span, cx[1:] / span])
+    return coef, breaks
+
+
+@jax.jit
+def _ref_power_stage(hist, lam, capacity, pdt, key):
+    """Each PD's usage row (lam * u) / cap, sorted and fitted per PD."""
+    n, npd = lam.shape
+    u_cl = hist[:, -28:].reshape(n, -1)
+    u_pd = (lam[..., None] * u_cl[:, None, :]).reshape(n * npd, -1)
+    cap = jnp.clip(capacity[:, None, None].repeat(npd, 1)
+                   .reshape(n * npd, 1), 1e-6, None)
+    u_norm = u_pd / cap
+    p_pd = power.simulate_pd_power(key, power.PDTruth(*pdt), u_norm)
+    return jax.vmap(_ref_fit)(u_norm, p_pd)
+
+
+@pytest.mark.parametrize("npd,window", [
+    (1, "random"), (2, "random"), (4, "random"), (2, "ties"),
+    (2, "flat")])
+def test_power_stage_matches_per_pd_fit(npd, window):
+    """One sort per cluster, the PD order statistics mapped from it, and
+    the distinct normal-equation entries: the same bits as sorting and
+    fitting every PD row. ``ties`` rounds usage to a coarse grid;
+    ``flat`` holds one cluster constant and another within 1e-5, so
+    their spans hit the 1e-6 clip."""
+    rng = np.random.RandomState(npd)
+    n = 16
+    hist = rng.uniform(50, 400, (n, 30, 24)).astype(np.float32)
+    if window == "ties":
+        hist = np.round(hist / 25) * 25
+    if window == "flat":
+        hist[3] = 123.0
+        hist[5] = 200.0 + 1e-5 * rng.rand(30, 24)
+    lam = rng.dirichlet(np.ones(npd), n).astype(np.float32)
+    cap = rng.uniform(300, 900, n).astype(np.float32)
+    pdt = tuple(jnp.asarray(rng.uniform(a, b, n * npd), jnp.float32)
+                for a, b in ((60, 100), (250, 400), (0.7, 1.3)))
+    key = jax.random.PRNGKey(7)
+    coef, breaks = _ref_power_stage(hist, lam, cap, pdt, key)
+    got = jax.jit(lambda h, lm, c, p, k: stages.power_stage(
+        h, lm, c, power.PDTruth(*p), k))(hist, lam, cap, pdt, key)
+    np.testing.assert_array_equal(np.asarray(got.coef), np.asarray(coef))
+    np.testing.assert_array_equal(np.asarray(got.breaks),
+                                  np.asarray(breaks))
+
+
+@pytest.mark.parametrize("qs", [power.BREAK_QS, [0.0], [1.0]],
+                         ids=["fit", "q0", "q1"])
+def test_quantiles_sorted_matches_jnp_quantile(qs):
+    """Order statistics of a sorted row, mapped through a non-decreasing
+    map and combined, equal ``jnp.quantile`` of the mapped row bitwise
+    (rows with ties included)."""
+    rng = np.random.RandomState(0)
+    x = rng.uniform(0, 300, (6, 672)).astype(np.float32)
+    x[1] = np.round(x[1] / 50) * 50
+    lam = jnp.asarray(rng.uniform(0.1, 0.9, (6, 3)), jnp.float32)
+    cap = jnp.asarray(rng.uniform(300, 900, (6, 1, 1)), jnp.float32)
+
+    def f(v):                       # (6, m) -> (6, 3, m)
+        return (lam[..., None] * v[:, None, :]) / cap
+
+    qs = np.asarray(qs, np.float32)
+    got = jax.jit(lambda x: power.quantiles_sorted(
+        jnp.sort(x, axis=-1, stable=False), qs, f))(x)
+    want = jax.jit(lambda x: jnp.moveaxis(
+        jnp.quantile(f(x), qs, axis=-1), 0, -1))(x)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("t", [672, 90, 7])
+def test_normal_equations_match_full_outer_products(t):
+    """The 19 distinct sums equal ``_pairwise_sum`` of the full outer
+    products bitwise, alone and batched (t = 7 takes the zero-padded
+    halvings)."""
+    rng = np.random.RandomState(t)
+    x = jnp.asarray(rng.uniform(0, 1, (3, t)), jnp.float32)
+    q = jnp.asarray(np.sort(rng.uniform(0, 1, (3, power.N_BREAKS)), -1),
+                    jnp.float32)
+    y = jnp.asarray(rng.uniform(100, 400, (3, t)), jnp.float32)
+
+    def full(x, q, y):
+        X = _ref_basis(x, q)
+        return (power._pairwise_sum(X[:, :, None] * X[:, None, :], axis=-3),
+                power._pairwise_sum(X * y[:, None], axis=-2))
+
+    for got, want in (
+            (jax.jit(jax.vmap(power.normal_equations))(x, q, y),
+             jax.jit(jax.vmap(full))(x, q, y)),
+            (jax.jit(power.normal_equations)(x[0], q[0], y[0]),
+             jax.jit(full)(x[0], q[0], y[0]))):
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
 def test_usage_fractions_near_constant():
